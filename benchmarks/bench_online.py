@@ -42,7 +42,7 @@ import numpy as np
 from bench_serve import CatalogSpec, drift_stream, make_catalog, recall_at
 from common import emit
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import model, online, simlsh, topk
 from repro.core.sgd import Hyper
 from repro.loop import LoopConfig, OnlineLoop
@@ -473,4 +473,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

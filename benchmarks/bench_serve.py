@@ -78,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import simlsh, topk
 from repro.core.model import Params, pack_serve_planes
 from repro.data.sparse import from_coo
@@ -490,9 +490,10 @@ def fault_scenario(*, batch: int, topn: int, probe: int, seed: int = 0):
 
 def sharded_child(*, N: int, D: int, batch: int, batches: int, probe: int,
                   topn: int, seed: int = 0) -> dict:
-    """Body of the sharded arm — runs inside a subprocess whose XLA was
-    forced to D host devices (`run_sharded_arm` sets the env; device
-    count is immutable after jax import, so the parent can't do this).
+    """Body of the sharded arm — over D real devices in this process, or
+    inside a subprocess whose XLA was forced to D host devices
+    (`run_sharded_arm` picks; the host device count is fixed once the
+    backend starts, so a CPU parent can't do this itself).
 
     Measures, in one window on one catalog: the D-sharded walk service
     (mesh-partitioned col plane + LSH index, ppermute butterfly top-N
@@ -538,13 +539,14 @@ def sharded_child(*, N: int, D: int, batch: int, batches: int, probe: int,
         emit(f"serve.sharded.qps.N{N}.D{d}", 1.0 / max(st["qps"], 1e-9),
              f"qps={st['qps']:.0f};recall={recalls[str(d)]:.3f}")
     cpu = os.cpu_count() or 1
+    forced = jax.default_backend() == "cpu"
     return dict(
         N=N, D=D, M=M, nnz=sp.nnz, batch=batch, batches=batches, topn=topn,
-        devices_forced=D, cpu_count=cpu,
+        devices_forced=D if forced else 0, cpu_count=cpu,
         # forced host devices time-slice the real cores: with fewer than
         # 2·D cores the scaling number measures the scheduler, not the
         # shard tier, and only the sanity floor applies (README rationale)
-        hardware_bound=cpu < 2 * D,
+        hardware_bound=forced and cpu < 2 * D,
         qps=qps, scaling_ratio=qps[str(D)] / max(qps["1"], 1e-9),
         recall_sharded=recalls[str(D)], recall_single=recalls["1"],
         recall_delta=recalls[str(D)] - recalls["1"],
@@ -553,10 +555,14 @@ def sharded_child(*, N: int, D: int, batch: int, batches: int, probe: int,
 
 def run_sharded_arm(*, N: int, batch: int, batches: int, probe: int,
                     topn: int, seed: int, D: int = SHARD_D) -> dict:
-    """Launch `sharded_child` in a subprocess with D forced host devices
-    (the same pattern as the pr1/pr7 same-window worktree arms)."""
+    """Run `sharded_child`.  On an accelerator this process already holds
+    the chips (a child could not open them), so the arm runs here over
+    every local device.  On CPU it runs in a subprocess with D forced
+    host devices."""
     kw = dict(N=N, D=D, batch=batch, batches=batches, probe=probe,
               topn=topn, seed=seed)
+    if jax.default_backend() != "cpu":
+        return sharded_child(**dict(kw, D=jax.device_count()))
     code = ("import json\n"
             "from benchmarks import bench_serve as b\n"
             f"print('SHARDJSON:' + json.dumps(b.sharded_child(**{kw!r})))\n")
@@ -887,4 +893,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

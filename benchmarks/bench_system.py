@@ -1,9 +1,7 @@
-"""System-level benchmarks: Pallas kernels, rotation scaling, roofline table.
+"""System-level benchmarks: Pallas kernels, roofline table.
 
 * kernels: interpret-mode µs/call vs the pure-jnp oracle (NOTE: interpret
   mode is a correctness harness — TPU wall-clock is the dry-run's domain);
-* rotation: MCUSGD++ epoch on 1 vs 4 host devices (subprocess, own XLA
-  device count — the paper's multi-GPU scaling experiment);
 * roofline: re-emit the dry-run sweep's per-cell terms as CSV (reads
   reports/dryrun/16x16; run `python -m repro.launch.dryrun --all --roofline`
   first for the full table).
@@ -12,9 +10,6 @@ from __future__ import annotations
 
 import glob
 import json
-import os
-import subprocess
-import sys
 
 import jax.numpy as jnp
 import numpy as np
@@ -34,7 +29,7 @@ def bench_kernels():
     N, deg, bits = 512, 128, 24
     psi = jnp.asarray(rng.normal(size=(N, deg)).astype(np.float32))
     phi = jnp.asarray(rng.choice([-1., 1.], (N, deg, bits)).astype(np.float32))
-    _, t_int = timed(simlsh_encode, psi, phi, repeat=3)
+    _, t_int = timed(simlsh_encode, psi, phi, repeat=3, interpret=True)
     _, t_ref = timed(simlsh_encode_ref, psi, phi, repeat=3)
     emit("kernel.simlsh_encode.interpret", t_int,
          f"ref_us={t_ref*1e6:.0f};bytes={psi.nbytes + phi.nbytes}")
@@ -43,62 +38,17 @@ def bench_kernels():
     a = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
     args = (a(B, F), a(B, F), a(B, K), a(B, K), a(B, K), a(B, K),
             a(B), a(B), a(B))
-    _, t_int = timed(neighbor_predict, *args, repeat=3)
+    _, t_int = timed(neighbor_predict, *args, repeat=3, interpret=True)
     _, t_ref = timed(neighbor_predict_ref, *args, repeat=3)
     emit("kernel.neighbor_predict.interpret", t_int, f"ref_us={t_ref*1e6:.0f}")
 
     u, v, r = a(B, F), a(B, F), a(B)
     valid = jnp.ones((B,), jnp.float32)
     _, t_int = timed(mf_sgd_step, u, v, r, valid, 0.02, 0.02, 0.01, 0.01,
-                     repeat=3)
+                     repeat=3, interpret=True)
     _, t_ref = timed(mf_sgd_step_ref, u, v, r, valid, 0.02, 0.02, 0.01, 0.01,
                      repeat=3)
     emit("kernel.mf_sgd.interpret", t_int, f"ref_us={t_ref*1e6:.0f}")
-
-
-ROTATION_SCRIPT = r"""
-import os, sys, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-import dataclasses, jax, jax.numpy as jnp, numpy as np
-from repro.core.sgd import Hyper
-from repro.data import synthetic as syn
-from repro.dist.rotation import make_rotation_epoch, stage_blocks
-D = %d
-M, N, F = 1024, 512, 32
-spec = dataclasses.replace(syn.MOVIELENS_LIKE, M=M, N=N, nnz=60000)
-rows, cols, vals, _ = syn.generate(spec, 0)
-staged = stage_blocks(rows, cols, vals, M, N, D)
-rng = np.random.default_rng(0)
-U = jnp.asarray(rng.normal(size=(M, F)).astype(np.float32) * .1)
-V = jnp.asarray(rng.normal(size=(N, F)).astype(np.float32) * .1)
-mesh = jax.make_mesh((D,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
-fn = jax.jit(make_rotation_epoch(mesh, D, M, N, Hyper(), batch=1024))
-args = [jnp.asarray(staged[k]) for k in ("i", "j", "r", "valid")]
-with jax.sharding.set_mesh(mesh):
-    U1, V1 = fn(U, V, *args, jnp.asarray(0))   # compile
-    jax.block_until_ready(U1)
-    t0 = time.perf_counter()
-    for e in range(3):
-        U1, V1 = fn(U1, V1, *args, jnp.asarray(e))
-    jax.block_until_ready(U1)
-print((time.perf_counter() - t0) / 3)
-"""
-
-
-def bench_rotation():
-    env = os.environ.copy()
-    env["PYTHONPATH"] = "src"
-    base = None
-    for D in (1, 2, 4):
-        r = subprocess.run([sys.executable, "-c", ROTATION_SCRIPT % (D, D)],
-                           capture_output=True, text=True, env=env,
-                           timeout=900)
-        if r.returncode != 0:
-            emit(f"rotation.D{D}", 0.0, "FAILED")
-            continue
-        secs = float(r.stdout.strip().splitlines()[-1])
-        base = base or secs
-        emit(f"rotation.D{D}", secs, f"speedup={base/secs:.2f}x")
 
 
 def bench_roofline():
@@ -119,5 +69,4 @@ def bench_roofline():
 
 def run_all():
     bench_kernels()
-    bench_rotation()
     bench_roofline()

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import model, sgd, simlsh, topk
 from repro.data import synthetic as syn
 from repro.data.sparse import conflict_free_schedule, from_coo, train_test_split
@@ -334,4 +334,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -10,6 +10,8 @@ import argparse
 import sys
 import traceback
 
+from repro import compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -43,4 +45,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

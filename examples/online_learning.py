@@ -9,6 +9,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import model, online
 from repro.core.sgd import Hyper
 from repro.core.simlsh import SimLSHConfig
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -11,6 +11,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.simlsh import SimLSHConfig
 from repro.data import synthetic as syn
 from repro.data.sparse import train_test_split
@@ -34,4 +35,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

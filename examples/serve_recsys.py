@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import online, simlsh, topk
 from repro.core.simlsh import SimLSHConfig
 from repro.data import synthetic as syn
@@ -187,6 +188,7 @@ def online_loop_main(args):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--online-loop", action="store_true",
                     help="run the crash-safe always-on loop demo instead")

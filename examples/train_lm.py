@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import base as CB
 from repro.launch.train import synth_batch, train_loop
 from repro.models import lm, steps
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

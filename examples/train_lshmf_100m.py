@@ -16,7 +16,7 @@ import time
 
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core.simlsh import SimLSHConfig
 from repro.data import synthetic as syn
 from repro.data.sparse import train_test_split
@@ -89,4 +89,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

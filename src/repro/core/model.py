@@ -111,7 +111,7 @@ class ServePlanes:
     """
 
     row: jax.Array  # [M, F+1] float32 — U ‖ b
-    col: jax.Array  # [N, F+1] float32 — V ‖ b̂
+    col: jax.Array  # [N, ≥F+1] float32 — V ‖ b̂ (‖ zero lane padding)
     mu: jax.Array   # []
     F: int = dataclasses.field(metadata=dict(static=True))
 
@@ -120,12 +120,21 @@ class ServePlanes:
         return self.col.shape[0]
 
 
-def pack_serve_planes(p: Params) -> ServePlanes:
-    """Params → the two serving planes (one concatenate per side)."""
+def pack_serve_planes(p: Params, *, lanes: int = 1) -> ServePlanes:
+    """Params → the two serving planes (one concatenate per side).
+
+    ``lanes`` rounds the col plane's width up to a multiple with zero
+    columns past b̂: the `candidate_score` kernel DMAs whole 128-lane rows,
+    so services on that path build it with ``lanes=128`` once instead of
+    padding per flush."""
+    F = int(p.U.shape[1])
+    pad = (-(F + 1)) % lanes
     return ServePlanes(
         row=jnp.concatenate([p.U, p.b[:, None]], axis=1),
-        col=jnp.concatenate([p.V, p.bh[:, None]], axis=1),
-        mu=p.mu, F=int(p.U.shape[1]))
+        col=jnp.concatenate(
+            [p.V, p.bh[:, None], jnp.zeros((p.V.shape[0], pad), p.V.dtype)],
+            axis=1),
+        mu=p.mu, F=F)
 
 
 def unpack_serve_planes(sp: ServePlanes) -> Params:
@@ -265,6 +274,12 @@ class ScheduledData:
     schedule's block-padded id space (see `EpochSchedule` — train against
     `remap_params`-relaid parameters).
 
+    The three neighbour planes are stored ``[K, P]`` (one sample per
+    lane): on a TPU a ``[P, K]`` plane with K < 128 is either padded to
+    128 lanes or re-laid out whole for the kernel, which at 9.9M ratings
+    and K=32 is more memory than the chip has.  `slice_batch` hands out
+    the usual ``[B, K]`` batch planes.
+
     For ``mf_only`` fits the neighbour planes are built zero-width: the
     MF step never reads them and the [nnz, K] cache memory is skipped.
     """
@@ -272,9 +287,9 @@ class ScheduledData:
     i: jax.Array     # [P] int32 row ids
     j: jax.Array     # [P] int32 col ids
     r: jax.Array     # [P] float32 ratings
-    nb: jax.Array    # [P, K] int32 neighbour ids (J^K[j])
-    rnb: jax.Array   # [P, K] float32 r_{i, nb} (0 where unobserved)
-    expl: jax.Array  # [P, K] float32 explicit-slot mask
+    nb: jax.Array    # [K, P] int32 neighbour ids (J^K[j])
+    rnb: jax.Array   # [K, P] float32 r_{i, nb} (0 where unobserved)
+    expl: jax.Array  # [K, P] float32 explicit-slot mask
 
 
 @jax.tree_util.register_dataclass
@@ -300,39 +315,41 @@ class ShardData:
 
 
 def _ordered_planes(sp: SparseMatrix, JK: jax.Array, sched, order_ids,
-                    pad: int, *, mf_only: bool, chunk: int):
+                    pad: int, *, mf_only: bool, chunk: int,
+                    lanes: bool = False):
     """One binary-search sweep over ``order_ids``-ordered triples → the
     (i, j, r, nb, rnb, expl) planes padded by ``pad`` zero slots (chunked
     so the [chunk, K, log nnz] search intermediates stay off the
     high-water mark; written in schedule order directly so no second
     permutation pass is needed).  Ids are remapped into the schedule's
-    block-padded space when the schedule carries maps; rating lookups
-    always use the original ids."""
+    block-padded id space when the schedule carries maps; rating lookups
+    always use the original ids.  ``lanes`` builds the neighbour planes
+    ``[K, P]`` instead of ``[P, K]``, chunk by chunk."""
     n = int(order_ids.shape[0])
     has_map = sched.row_map.size > 0
-    padded = lambda a: jnp.concatenate(
-        [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
+    ax = 1 if lanes else 0
+    padded = lambda a, axis=0: jnp.concatenate(
+        [a, jnp.zeros(a.shape[:axis] + (pad,) + a.shape[axis + 1:], a.dtype)],
+        axis=axis)
     ri, cj = sp.rows[order_ids], sp.cols[order_ids]
     i = padded(sched.row_map[ri] if has_map else ri)
     j = padded(sched.col_map[cj] if has_map else cj)
     r = padded(sp.vals[order_ids])
-    if mf_only:
-        z2 = jnp.zeros((i.shape[0], 0), jnp.float32)
-        return i, j, r, z2.astype(jnp.int32), z2, z2
-    K = JK.shape[1]
-    nb = JK[cj]                      # original col ids (for the lookup)
-    rnb_parts, expl_parts = [], []
-    for c0 in range(0, n, chunk):
-        ii = ri[c0:c0 + chunk]
-        nn = nb[c0:c0 + chunk]
+    K = 0 if mf_only else JK.shape[1]
+    orient = (lambda a: a.T) if lanes else (lambda a: a)
+    parts = ([], [], [])
+    for c0 in range(0, n if K else 0, chunk):
+        ii, cc = ri[c0:c0 + chunk], cj[c0:c0 + chunk]
+        nn = JK[cc]                      # original col ids (for the lookup)
         rnb, hit = lookup(sp, jnp.broadcast_to(ii[:, None], nn.shape), nn)
-        rnb_parts.append(rnb)
-        expl_parts.append(hit.astype(jnp.float32))
-    z = jnp.zeros((0, K), jnp.float32)
-    rnb = jnp.concatenate(rnb_parts) if rnb_parts else z
-    expl = jnp.concatenate(expl_parts) if expl_parts else z
-    nb_stored = sched.col_map[nb] if has_map else nb
-    return i, j, r, padded(nb_stored), padded(rnb), padded(expl)
+        for out, a in zip(parts, (sched.col_map[nn] if has_map else nn, rnb,
+                                  hit.astype(jnp.float32))):
+            out.append(orient(a))
+    empty = (K, n + pad) if lanes else (n + pad, K)
+    planes = [padded(jnp.concatenate(p, axis=ax), ax) if p
+              else jnp.zeros(empty, dt)
+              for p, dt in zip(parts, (jnp.int32, jnp.float32, jnp.float32))]
+    return (i, j, r, *planes)
 
 
 def build_scheduled_data(sp: SparseMatrix, JK: jax.Array, sched, *,
@@ -343,7 +360,7 @@ def build_scheduled_data(sp: SparseMatrix, JK: jax.Array, sched, *,
     schedule has a shard tier."""
     return ScheduledData(*_ordered_planes(
         sp, JK, sched, sched.order[sched.shard_span:], sched.pad_width,
-        mf_only=mf_only, chunk=chunk))
+        mf_only=mf_only, chunk=chunk, lanes=True))
 
 
 def build_shard_data(sp: SparseMatrix, JK: jax.Array, sched, *,
@@ -362,10 +379,12 @@ def build_shard_data(sp: SparseMatrix, JK: jax.Array, sched, *,
 
 def slice_batch(sd: ScheduledData, start: jax.Array, width: int,
                 valid: jax.Array) -> Batch:
-    """Assemble a schedule-window batch: contiguous slices, zero gathers."""
+    """Assemble a schedule-window batch: contiguous slices, zero gathers
+    (the ``[K, P]`` neighbour planes come out as ``[B, K]``)."""
     sl = lambda a: jax.lax.dynamic_slice_in_dim(a, start, width, axis=0)
-    expl = sl(sd.expl)
-    return Batch(sl(sd.i), sl(sd.j), sl(sd.r), sl(sd.nb), sl(sd.rnb),
+    slt = lambda a: jax.lax.dynamic_slice_in_dim(a, start, width, axis=1).T
+    expl = slt(sd.expl)
+    return Batch(sl(sd.i), sl(sd.j), sl(sd.r), slt(sd.nb), slt(sd.rnb),
                  expl, 1.0 - expl, valid.astype(jnp.float32))
 
 

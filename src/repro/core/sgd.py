@@ -369,7 +369,6 @@ def _sharded_tier(pp: PackedParams, shd: ShardData, valid,
     Neighbour baselines b̂[nb] use the epoch-start snapshot ``bh0`` since
     neighbour cols cross block boundaries.  Planes must be in the
     schedule's block-padded id space (`model.remap_params`)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     D = sched.shards
@@ -416,7 +415,7 @@ def _sharded_tier(pp: PackedParams, shd: ShardData, valid,
         return rowb[None], colb[None]
 
     sh = P("shard")
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(sh, sh, P(), P(), P(), sh, sh),
         out_specs=(sh, sh))
@@ -436,7 +435,7 @@ def train_epoch_scheduled(pp: PackedParams, sd: ScheduledData,
                           shd: ShardData | None = None,
                           mf_only: bool = False, bce: bool = False,
                           use_kernels: bool = False, impl: str = "ref",
-                          interpret: bool = True, tile_b: int = 256,
+                          interpret: bool = False, tile_b: int = 256,
                           mesh=None) -> PackedParams:
     """One epoch over a tiered conflict-free schedule (the offline hot path).
 
@@ -488,9 +487,9 @@ def train_epoch_scheduled(pp: PackedParams, sd: ScheduledData,
         if not starts.shape[0]:
             continue
         order = jax.random.permutation(keys[2 + t], starts.shape[0])
-        # tile_b passes through unclamped: kernel._clamp_tile aligns the
-        # tile to the batch rounded up to the sublane multiple, which a
-        # min() against a non-power-of-two tier width would defeat
+        # tile_b passes through unclamped: the kernels fit the tile to the
+        # batch themselves (`_clamp_tile`, `_lane_tile`), which a min()
+        # against a non-power-of-two tier width would defeat
         pp = _cf_scan(pp, sd, starts[order], valid[order], hp, decay,
                       width=sched.widths[t], conflict_free=True,
                       tile_b=tile_b, **kw)

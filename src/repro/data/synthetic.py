@@ -61,17 +61,24 @@ def generate(spec: DatasetSpec, seed: int = 0):
     pu /= pu.sum()
     pi /= pi.sum()
 
-    # oversample until nnz unique pairs (zipf heads collide a lot)
-    rows_l, cols_l, seen = [], [], 0
+    # oversample until nnz unique pairs (zipf heads collide a lot).  The
+    # distinct keys drawn so far stay in one sorted array, so each round
+    # costs its own draw plus one linear merge, not a sort of everything
+    # drawn (the tail rounds are many and small at full size)
+    rows_l, cols_l = [], []
+    distinct = np.zeros((0,), np.int64)
     want = nnz
-    while seen < want:
-        take = int((want - seen) * 2.0) + 1024
+    while distinct.size < want:
+        take = int((want - distinct.size) * 2.0) + 1024
         r = rng.choice(M, size=take, p=pu).astype(np.int32)
         c = rng.choice(N, size=take, p=pi).astype(np.int32)
         rows_l.append(r)
         cols_l.append(c)
-        key = np.concatenate(rows_l).astype(np.int64) * N + np.concatenate(cols_l)
-        seen = len(np.unique(key))
+        new = np.unique(r.astype(np.int64) * N + c)
+        at = np.searchsorted(distinct, new)
+        known = at < distinct.size
+        known[known] = distinct[at[known]] == new[known]
+        distinct = np.insert(distinct, at[~known], new[~known])
     rows = np.concatenate(rows_l)
     cols = np.concatenate(cols_l)
     key = rows.astype(np.int64) * N + cols

@@ -24,7 +24,7 @@ from repro.kernels.candidate_score.ref import candidate_score_topn_ref
 
 @partial(jax.jit, static_argnames=("topn", "tile_b", "interpret", "impl"))
 def score_candidates(planes, user_ids: jax.Array, cand: jax.Array, *,
-                     topn: int, tile_b: int = 8, interpret: bool = True,
+                     topn: int, interpret: bool, tile_b: int = 8,
                      impl: str = "pallas"):
     """planes (`model.ServePlanes`; a `Params` is packed on the fly for
     compatibility), user_ids [B], cand [B, C] SENTINEL-padded →

@@ -17,11 +17,12 @@ from repro.kernels.candidate_score.kernel import NEG
 
 def candidate_score_topn_ref(urow, plane, cand, mask, *, topn: int,
                              tile_b: int = 8):
-    """urow [B, F+1] (= U‖(μ+b) rows, pre-gathered); plane [N, F+1] = V‖b̂;
-    cand [B, C] int32 ids (pre-clipped to [0, N)); mask [B, C] (1.0 valid)
-    → (scores [B, topn] f32, idx [B, topn] int32 slots into C)."""
+    """urow [B, F+1] (= U‖(μ+b) rows, pre-gathered); plane [N, ≥F+1] =
+    V‖b̂ (lanes past F+1 ignored); cand [B, C] int32 ids (pre-clipped to
+    [0, N)); mask [B, C] (1.0 valid) → (scores [B, topn] f32, idx
+    [B, topn] int32 slots into C)."""
     B, C = cand.shape
-    F = plane.shape[1] - 1
+    F = urow.shape[1] - 1
     pad = (-B) % tile_b
     if pad:
         urow = jnp.pad(urow, ((0, pad), (0, 0)))
@@ -31,7 +32,7 @@ def candidate_score_topn_ref(urow, plane, cand, mask, *, topn: int,
 
     def tile(_, args):
         u, c, m = args
-        rows = plane[c]                                  # [tile_b, C, F+1]
+        rows = plane[c][..., :F + 1]                     # [tile_b, C, F+1]
         s = (jnp.einsum("bf,bcf->bc", u[:, :F], rows[..., :F])
              + rows[..., F] + u[:, F][:, None])
         s = jnp.where(m > 0, s, NEG)

@@ -1,28 +1,26 @@
-"""Pallas TPU kernel: in-VMEM LSH bucket walk + dedup → candidate ids.
+"""Pallas TPU kernel: in-VMEM dedup of the LSH bucket walk → candidate ids.
 
-The other half of the serving hot path.  `candidate_score` moved the
-score-side gather into the chip; this kernel does the same for retrieval.
-Window *descriptors* (flat start + valid length per (seed, band) bucket
-window, from `serve.index.window_slices`) enter scalar-prefetched SMEM;
-the flattened sorted-id plane stays in HBM (`pltpu.ANY`) and each user's
-I windows are DMA'd as static ``cap``-wide slices into a VMEM scratch
-tile — the ``[B, pool]`` gathered-id intermediate and the host-side
-``[B, ~1100]`` dedup sort never exist in HBM.  The walk is
-double-buffered across grid steps: scratch persists between sequential
-programs, so while user ``b``'s pool is folded, user ``b+1``'s windows
-are already in flight.
+The other half of the serving hot path.  Window *descriptors* (flat start
++ valid length per (seed, band) bucket window, from
+`serve.index.window_slices`) expand into each user's raw pool with one
+XLA gather of static ``cap``-wide reads (`ref.window_pool`): a DMA from
+the HBM id plane must move whole tiles (1024 ids for a 1-D int32 array),
+so an 8-id window cannot be fetched on its own.  The pool is micro-batch
+sized (``[B, I·cap + X]``, ~1.3 MB at B=256); the ``[B, ~1100]`` host-side
+dedup sort of the legacy path never exists.
 
-In VMEM the pool (windows ‖ extras) is masked to the valid prefixes,
-exclusions knocked out, and pushed through the same invertible 30-bit
-multiplicative hash `retrieve.dedup_candidates` uses.  Dedup is two
-bitonic sorting networks over the power-of-two padded row: sort once
-(duplicate hashes become adjacent — the hash is injective on [0, 2³⁰)),
-mark repeats as INTMAX padding, sort again to compact, unhash the first
-C.  A sorting network is the right shape on-chip: ~log²(W)/2 static
-compare-exchange stages of full-row vector ops, no data-dependent
-control flow.  Output is exactly the `ref.lsh_retrieve_topc_ref`
-contract — unique ids in hashed order — so candidate ids can feed the
-`candidate_score` kernel's scalar-prefetch operand directly.
+The kernel takes 8 users per grid step as a lane-dense ``[8, Wp]`` tile
+(Wp the pool width rounded up to a power of two ≥ 128).  In VMEM the pool
+has its exclusions knocked out and is pushed through the same invertible
+30-bit multiplicative hash `retrieve.dedup_candidates` uses.  Dedup is
+two bitonic sorting networks along the lanes: sort once (duplicate hashes
+become adjacent — the hash is injective on [0, 2³⁰)), mark repeats as
+INTMAX padding, sort again to compact, unhash the first C.  Each
+compare-exchange stage is a pair of lane rotations plus min/max/select
+over the whole tile — no data-dependent control flow.  Output is exactly
+the `ref.lsh_retrieve_topc_ref` contract — unique ids in hashed order —
+so candidate ids can feed the `candidate_score` kernel's scalar-prefetch
+operand directly.
 """
 from __future__ import annotations
 
@@ -34,127 +32,94 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.topk import SENTINEL
-from repro.kernels.lsh_retrieve.ref import INTMAX, INV, MASK30, MULT
+from repro.kernels.lsh_retrieve.ref import (INTMAX, INV, MASK30, MULT,
+                                            window_pool)
+
+_ROWS = 8          # users per grid step: one sublane tile
 
 
-def _bitonic_sort_row(x):
-    """Ascending bitonic sort of one [1, W] int32 row, W a power of two.
-    Fully static: log(W)·(log(W)+1)/2 compare-exchange stages, each a
-    reshape + min/max/select over the whole row."""
+def _rotations(x, lane, d):
+    """(x rotated by d, x rotated by W - d, lane rotated by d) along the
+    lane axis.  Callers pick between the two rotations by where the
+    rotated ``lane`` says each value came from, so nothing depends on the
+    rotate direction convention."""
+    W = x.shape[1]
+    return (pltpu.roll(x, d, 1), pltpu.roll(x, W - d, 1),
+            pltpu.roll(lane, d, 1))
+
+
+def _partner(x, lane, j):
+    """``x[:, lane ^ j]`` (j a power of two below the width)."""
+    fwd, bwd, came_from = _rotations(x, lane, j)
+    return jnp.where(came_from == (lane ^ j), fwd, bwd)
+
+
+def _bitonic_sort_rows(x):
+    """Ascending bitonic sort of each row of an int32 [R, W] tile, W a
+    power of two.  Fully static: log(W)·(log(W)+1)/2 compare-exchange
+    stages, each two lane rotations + min/max/select over the tile."""
     W = x.shape[1]
     assert W & (W - 1) == 0, "bitonic width must be a power of two"
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     k = 2
     while k <= W:
         j = k // 2
         while j >= 1:
-            y = x.reshape(W // (2 * j), 2, j)
-            a, b = y[:, 0, :], y[:, 1, :]
-            # element index of a[g, t] is g·2j + t; ascending block iff
-            # its index has bit k clear (the standard bitonic direction)
-            idx = (jax.lax.broadcasted_iota(jnp.int32, (W // (2 * j), j), 0)
-                   * (2 * j)
-                   + jax.lax.broadcasted_iota(jnp.int32, (W // (2 * j), j), 1))
-            up = (idx & k) == 0
-            lo = jnp.minimum(a, b)
-            hi = jnp.maximum(a, b)
-            y = jnp.stack([jnp.where(up, lo, hi), jnp.where(up, hi, lo)],
-                          axis=1)
-            x = y.reshape(1, W)
+            p = _partner(x, lane, j)
+            # a block sorts ascending iff its lanes have bit k clear; the
+            # lower lane of a pair keeps the min there, the max elsewhere
+            keep_min = ((lane & j) == 0) == ((lane & k) == 0)
+            x = jnp.where(keep_min, jnp.minimum(x, p), jnp.maximum(x, p))
             j //= 2
         k *= 2
     return x
 
 
-def _retrieve_kernel(starts_ref, exclude_ref, lens_ref, extra_ref, ids_ref,
-                     cand_out, wins, sem, *, C: int, cap: int, E: int,
-                     Wp: int):
-    """starts_ref [B, I] int32 SMEM (scalar prefetch); exclude_ref [E]
-    int32 SMEM (scalar prefetch); lens_ref [1, I] VMEM; extra_ref [1, X]
-    VMEM; ids_ref [q·N + cap] in ANY/HBM; cand_out [1, C]; wins
-    [2, I, cap] VMEM scratch (double buffer); sem [2] DMA."""
-    I = lens_ref.shape[1]
-    X = extra_ref.shape[1]
-    b = pl.program_id(0)
-    nb = pl.num_programs(0)
-
-    def win_dma(slot, u, i):
-        # one static cap-wide window slice, HBM → the slot's scratch row;
-        # the id plane's SENTINEL apron keeps the tail read in-bounds
-        return pltpu.make_async_copy(
-            ids_ref.at[pl.ds(starts_ref[u, i], cap)],
-            wins.at[slot, i], sem.at[slot])
-
-    def start_user(slot, u):
-        jax.lax.fori_loop(
-            0, I, lambda i, _: (win_dma(slot, u, i).start(), 0)[1], 0)
-
-    def wait_user(slot, u):
-        jax.lax.fori_loop(
-            0, I, lambda i, _: (win_dma(slot, u, i).wait(), 0)[1], 0)
-
-    slot = jax.lax.rem(b, 2)
-
-    @pl.when(b == 0)
-    def _():                       # cold start: first user's windows
-        start_user(0, 0)
-
-    @pl.when(b + 1 < nb)
-    def _():                       # prefetch next user into the other slot
-        start_user(1 - slot, b + 1)
-
-    wait_user(slot, b)
-
-    ok = (jax.lax.broadcasted_iota(jnp.int32, (I, cap), 1)
-          < lens_ref[0, :][:, None])
-    pool = jnp.where(ok, wins[slot], SENTINEL).reshape(1, I * cap)
-    pool = jnp.concatenate([pool, extra_ref[...]], axis=1)  # [1, I·cap + X]
-    for e in range(E):             # static unroll over the tiny exclude set
+def _dedup_kernel(exclude_ref, pool_ref, cand_out, *, C: int, E: int):
+    """exclude_ref [E] int32 SMEM (scalar prefetch); pool_ref [8, Wp]
+    int32 VMEM (SENTINEL-padded raw pool); cand_out [8, C]."""
+    pool = pool_ref[...]
+    for e in range(E):             # static unroll over the small exclude set
         pool = jnp.where(pool == exclude_ref[e], SENTINEL, pool)
     valid = (pool != SENTINEL) & (pool >= 0)
     h = jnp.where(valid, (pool * MULT) & MASK30, INTMAX)
-    W = I * cap + X
-    if Wp > W:
-        h = jnp.concatenate(
-            [h, jnp.full((1, Wp - W), INTMAX, jnp.int32)], axis=1)
-    h = _bitonic_sort_row(h)
-    prev = jnp.concatenate(
-        [jnp.full((1, 1), -1, jnp.int32), h[:, :-1]], axis=1)
+    h = _bitonic_sort_rows(h)
+    lane = jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
+    fwd, bwd, came_from = _rotations(h, lane, 1)
+    prev = jnp.where(came_from == lane - 1, fwd, bwd)     # h[:, lane - 1]
+    prev = jnp.where(lane == 0, -1, prev)
     h = jnp.where((h != prev) & (h != INTMAX), h, INTMAX)
-    h = _bitonic_sort_row(h)       # compact survivors left
+    h = _bitonic_sort_rows(h)      # compact survivors left
     keys = h[:, :C]
     cand_out[...] = jnp.where(keys != INTMAX, (keys * INV) & MASK30, SENTINEL)
 
 
 @functools.partial(jax.jit, static_argnames=("C", "cap", "interpret"))
 def lsh_retrieve_topc(starts, lens, extra, ids_flat, exclude, *, C: int,
-                      cap: int, interpret: bool = True):
+                      cap: int, interpret: bool):
     """starts/lens [B, I] int32 window descriptors; extra [B, X] int32
     SENTINEL-padded appended ids; ids_flat [q·N + cap] int32
-    (`padded_flat_ids` — the apron is load-bearing, see `win_dma`);
+    (`padded_flat_ids` — the apron keeps every cap-wide read in bounds);
     exclude [E] int32 → cand [B, C] int32 unique ids, SENTINEL-padded,
     in hashed order (the `ref.lsh_retrieve_topc_ref` contract)."""
-    B, I = starts.shape
-    X = extra.shape[1]
-    W = I * cap + X
+    pool = window_pool(starts, lens, extra, ids_flat, cap=cap)
+    B, W = pool.shape
     assert C <= W, f"candidate budget C={C} exceeds pool width {W}"
-    Wp = 1 << (W - 1).bit_length()             # next power of two
+    Wp = max(128, 1 << (W - 1).bit_length())   # power of two, ≥ one vreg
+    pool = jnp.pad(pool, ((0, (-B) % _ROWS), (0, Wp - W)),
+                   constant_values=SENTINEL)
     E = exclude.shape[0]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # starts, exclude → SMEM
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, I), lambda b, *_: (b, 0)),
-            pl.BlockSpec((1, X), lambda b, *_: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # id plane stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda b, *_: (b, 0)),
-        scratch_shapes=[pltpu.VMEM((2, I, cap), jnp.int32),
-                        pltpu.SemaphoreType.DMA((2,))],
+        num_scalar_prefetch=1,                 # exclude → SMEM
+        grid=(pool.shape[0] // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, Wp), lambda b, *_: (b, 0))],
+        out_specs=pl.BlockSpec((_ROWS, C), lambda b, *_: (b, 0)),
     )
-    return pl.pallas_call(
-        functools.partial(_retrieve_kernel, C=C, cap=cap, E=E, Wp=Wp),
+    cand = pl.pallas_call(
+        functools.partial(_dedup_kernel, C=C, E=E),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((pool.shape[0], C), jnp.int32),
         interpret=interpret,
-    )(starts, exclude, lens, extra, ids_flat)
+    )(exclude, pool)
+    return cand[:B]

@@ -1,13 +1,12 @@
 """jit'd wrapper: seeds → window descriptors → in-VMEM walk + dedup →
 [B, C] candidate ids.
 
-The retrieval-side twin of `candidate_score.ops.score_candidates`: host
-code builds only the micro-batch-sized descriptor tensors (starts/lens
-[B, I], tail extras [B, X]); the catalog-sized work — walking the bucket
-windows and deduplicating the union — happens inside the kernel against
-the HBM-resident id plane.  The output feeds `score_candidates`'s
-scalar-prefetch candidate operand directly, so on TPU the fused
-recommend path is two chained kernels with no [B, pool] intermediate.
+The retrieval-side twin of `candidate_score.ops.score_candidates`: the
+program builds only micro-batch-sized tensors (descriptors starts/lens
+[B, I], tail extras [B, X], the expanded [B, I·cap + X] window pool); the
+dedup of the union happens inside the kernel.  The output feeds
+`score_candidates`'s scalar-prefetch candidate operand directly, so on
+TPU the fused recommend path is two chained kernels in one program.
 
 ``impl='ref'`` swaps in the pure-jnp oracle (`ref.lsh_retrieve_topc_ref`)
 with the identical contract — the CPU path, where Pallas only has the
@@ -35,8 +34,8 @@ from repro.serve.retrieve import seed_items, tail_hits
 def retrieve_candidates(index: LSHIndex, sp: SparseMatrix,
                         user_ids: jax.Array, *, n_seeds: int, cap: int,
                         C: int, popular: jax.Array | None = None,
-                        window: int = 64, tail_scan: bool = True,
-                        interpret: bool = True, impl: str = "pallas",
+                        interpret: bool, window: int = 64,
+                        tail_scan: bool = True, impl: str = "pallas",
                         ids_flat: jax.Array | None = None) -> jax.Array:
     """user_ids [B] → cand [B, C] int32 unique candidate ids,
     SENTINEL-padded.  Same slot layout as `retrieve.finalize_candidates`:

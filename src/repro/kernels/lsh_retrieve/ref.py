@@ -3,9 +3,10 @@
 Mirrors the kernel's contract bit for bit: window descriptors come in
 (flat starts + valid lengths from `serve.index.window_slices`), each
 descriptor is expanded as a static ``cap``-wide read of the padded flat
-id plane, extras (tail hits) are appended, exclusions and invalid slots
-are masked, and the surviving ids are deduplicated through the same
-invertible 30-bit multiplicative hash the kernel sorts in VMEM.  The
+id plane (`window_pool`, shared with the kernel wrapper), extras (tail
+hits) are appended, exclusions and invalid slots are masked, and the
+surviving ids are deduplicated through the same invertible 30-bit
+multiplicative hash the kernel sorts in VMEM.  The
 output is each user's first C unique ids in *hashed* order — identical
 to the kernel because both reduce to "sort the same multiset of hash
 keys, drop duplicate neighbours, sort again, unhash the first C".
@@ -30,6 +31,19 @@ MASK30 = 0x3FFFFFFF
 INTMAX = 0x7FFFFFFF
 
 
+def window_pool(starts, lens, extra, ids_flat, *, cap: int):
+    """Expand window descriptors into the raw candidate pool: each
+    (starts, lens) pair is a static ``cap``-wide read of the padded flat
+    id plane, masked to its valid prefix, with the extras appended →
+    [B, I·cap + X] int32, SENTINEL where masked (duplicates intact)."""
+    B, I = starts.shape
+    pos = starts[:, :, None] + jnp.arange(cap, dtype=jnp.int32)    # [B,I,cap]
+    ids = ids_flat[pos]
+    ok = jnp.arange(cap, dtype=jnp.int32)[None, None, :] < lens[:, :, None]
+    return jnp.concatenate(
+        [jnp.where(ok, ids, SENTINEL).reshape(B, I * cap), extra], axis=1)
+
+
 def lsh_retrieve_topc_ref(starts, lens, extra, ids_flat, exclude, *,
                           C: int, cap: int):
     """starts/lens [B, I] int32 (`window_slices` descriptors); extra
@@ -37,12 +51,8 @@ def lsh_retrieve_topc_ref(starts, lens, extra, ids_flat, exclude, *,
     ids_flat [q·N + cap] int32 (`padded_flat_ids`); exclude [E] int32 ids
     dropped from the output (SENTINEL entries inert) → cand [B, C] int32,
     each user's unique pool ids in hashed order, SENTINEL-padded."""
-    B, I = starts.shape
-    pos = starts[:, :, None] + jnp.arange(cap, dtype=jnp.int32)    # [B,I,cap]
-    ids = ids_flat[pos]
-    ok = jnp.arange(cap, dtype=jnp.int32)[None, None, :] < lens[:, :, None]
-    pool = jnp.concatenate(
-        [jnp.where(ok, ids, SENTINEL).reshape(B, I * cap), extra], axis=1)
+    B = starts.shape[0]
+    pool = window_pool(starts, lens, extra, ids_flat, cap=cap)
     excluded = jnp.any(pool[:, :, None] == exclude[None, None, :], axis=2)
     valid = (pool != SENTINEL) & (pool >= 0) & ~excluded
     h = jnp.where(valid, (pool * jnp.int32(MULT)) & jnp.int32(MASK30),

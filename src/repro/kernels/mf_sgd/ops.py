@@ -35,8 +35,8 @@ def resolve_impl(impl: str) -> str:
 
 
 def apply_mf_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
-                 impl: str = "pallas", tile_b: int = 256,
-                 interpret: bool = True, bce: bool = False) -> PackedParams:
+                 interpret: bool, impl: str = "pallas", tile_b: int = 256,
+                 bce: bool = False) -> PackedParams:
     """CUSGD++ step applied to the packed planes via a conflict-free batch
     (only the U/V columns are touched)."""
     F = pp.F
@@ -56,8 +56,8 @@ def apply_mf_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
 
 
 def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
-                    impl: str = "pallas", tile_b: int = 256,
-                    interpret: bool = True, bce: bool = False) -> PackedParams:
+                    interpret: bool, impl: str = "pallas", tile_b: int = 256,
+                    bce: bool = False) -> PackedParams:
     """Fused six-parameter CULSH-MF step applied to the packed planes.
 
     XLA-level gathers assemble the plane tiles (same split as
@@ -66,9 +66,13 @@ def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
     which needs rows of the col plane the batch doesn't own.
     """
     F, K = pp.F, pp.K
-    row = pp.row[bt.i]                      # [B, F+1]
-    col = pp.col[bt.j]                      # [B, F+2K+1]
-    bh_nb = pp.col[bt.nb, F + 2 * K]        # [B, K]
+    # the kernel takes batch-minor tiles: the transposes of the [B, K]
+    # batch planes cancel the ones `model.slice_batch` makes, so the
+    # schedule's [K, P] planes reach the kernel without a re-layout
+    row = pp.row[bt.i].T                    # [F+1, B]
+    col = pp.col[bt.j].T                    # [F+2K+1, B]
+    nb = bt.nb.T                            # [K, B]
+    bh_nb = pp.col[nb, F + 2 * K]
     d = decay
     hpv = jnp.stack([hp.a_b * d, hp.a_bh * d, hp.a_u * d, hp.a_v * d,
                      hp.a_w * d, hp.a_c * d,
@@ -77,8 +81,8 @@ def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
                      jnp.float32(hp.l_w), jnp.float32(hp.l_c), pp.mu])
     step = (culsh_sgd_step_ref if impl == "ref"
             else partial(culsh_sgd_step, tile_b=tile_b, interpret=interpret))
-    row2, col2 = step(row, col, bt.rnb, bh_nb, bt.expl, bt.r, bt.valid, hpv,
-                      bce=bce)
+    row2, col2 = step(row, col, bt.rnb.T, bh_nb, bt.expl.T, bt.r, bt.valid,
+                      hpv, bce=bce)
     return dataclasses.replace(
-        pp, row=pp.row.at[bt.i].add(row2 - row),
-        col=pp.col.at[bt.j].add(col2 - col))
+        pp, row=pp.row.at[bt.i].add((row2 - row).T),
+        col=pp.col.at[bt.j].add((col2 - col).T))

@@ -24,16 +24,19 @@ def culsh_sgd_step_ref(row, col, rnb, bh_nb, expl, r, valid, hp, *,
                        bce: bool = False):
     """Fused six-parameter Eq. (5) step on a conflict-free packed tile.
 
-    Packed-plane operands (see `model.PackedParams`): ``row [B, F+1]`` =
-    U‖b and ``col [B, F+2K+1]`` = V‖W‖C‖b̂ are row-aligned gathers of the
-    two parameter planes; ``hp`` packs the 12 decayed hyper scalars
+    Operands are batch-minor (one sample per column, as the kernel takes
+    them): ``row [F+1, B]`` = U‖b and ``col [F+2K+1, B]`` = V‖W‖C‖b̂ are
+    gathers of the two packed parameter planes (`model.PackedParams`),
+    ``rnb``, ``bh_nb`` and ``expl`` are ``[K, B]``, ``r`` and ``valid``
+    are ``[B]``; ``hp`` packs the 12 decayed hyper scalars
     ``(γb, γb̂, γu, γv, γw, γc, λb, λb̂, λu, λv, λw, λc)`` plus ``μ``.
     The Eq. (1) forward (including b̄, residuals and the |R|/|N|
     normalizers) happens *inside* the step — only the neighbour-baseline
     gather ``bh_nb`` = b̂[J^K[j]] needs the full plane and stays outside.
-    Returns the two updated tiles; `ops.apply_culsh_sgd` turns them into
-    one delta-scatter per plane.
+    Returns the two updated tiles, batch-minor; `ops.apply_culsh_sgd`
+    turns them into one delta-scatter per plane.
     """
+    row, col, rnb, bh_nb, expl = (a.T for a in (row, col, rnb, bh_nb, expl))
     F = row.shape[-1] - 1
     K = rnb.shape[-1]
     gb, gbh, gu, gv, gw, gc = (hp[k] for k in range(6))
@@ -57,4 +60,4 @@ def culsh_sgd_step_ref(row, col, rnb, bh_nb, expl, r, valid, hp, *,
     c2 = c + gc * (sN[:, None] * eb - lc * c) * impl * vm
     row2 = jnp.concatenate([u2, b2[:, None]], axis=1)
     col2 = jnp.concatenate([v2, w2, c2, bh2[:, None]], axis=1)
-    return row2, col2
+    return row2.T, col2.T

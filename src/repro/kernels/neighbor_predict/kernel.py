@@ -37,7 +37,7 @@ def _predict_kernel(u_ref, v_ref, w_ref, c_ref, resid_ref, impl_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def neighbor_predict(u, v, w, c, resid, impl, bbar, sR, sN, *,
-                     tile_b: int = 128, interpret: bool = True):
+                     interpret: bool, tile_b: int = 128):
     """All inputs row-aligned on the batch dim B → pred [B] f32."""
     B, F = u.shape
     K = w.shape[1]

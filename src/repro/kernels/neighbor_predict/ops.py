@@ -15,7 +15,7 @@ from repro.core.model import Batch, Params
 from repro.kernels.neighbor_predict.kernel import neighbor_predict
 
 
-def predict_batch(p: Params, bt: Batch, *, interpret: bool = True):
+def predict_batch(p: Params, bt: Batch, *, interpret: bool):
     bbar = p.mu + p.b[bt.i] + p.bh[bt.j]
     bbar_nb = p.mu + p.b[bt.i][:, None] + p.bh[bt.nb]
     resid = (bt.rnb - bbar_nb) * bt.expl

@@ -34,7 +34,7 @@ def _encode_kernel(psi_ref, phi_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
-def simlsh_encode(psi, phi, *, tile_n: int = 8, interpret: bool = True):
+def simlsh_encode(psi, phi, *, interpret: bool, tile_n: int = 8):
     """psi [N, deg] f32, phi [N, deg, bits] f32 → S [N, bits] f32."""
     N, deg = psi.shape
     bits = phi.shape[-1]

@@ -34,7 +34,7 @@ def ell_pack(sp: SparseMatrix, deg: int):
 
 
 def encode_band(sp: SparseMatrix, cfg: SimLSHConfig, key, band, *,
-                deg: int = 128, interpret: bool = True):
+                interpret: bool, deg: int = 128):
     """One band's pre-sign accumulators via the Pallas kernel. [N, bits]."""
     ids, vals = ell_pack(sp, deg)
     w = psi(vals, cfg.psi_pow, cfg.psi_mode, cfg.psi_center) * (vals != 0)

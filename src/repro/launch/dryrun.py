@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import anywhere (jax locks the
-# device count at first init).  Everything below is ordinary code.
-
 _DOC = """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell:
@@ -22,6 +17,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -31,8 +27,12 @@ import jax
 from repro.configs import base as CB
 from repro.launch import roofline as RL
 from repro.launch import specs as SPECS
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import lm, sharding, steps
+
+# the production mesh is 512 virtual host devices; XLA reads the count
+# when the backend starts, so the flag is set before anything touches it
+_FORCE_DEVICES = "--xla_force_host_platform_device_count=512"
 
 
 def build_cell(cfg, shape, mesh, axes):
@@ -73,7 +73,7 @@ def run_cell(arch: str, shape_name: str, mesh, *, do_roofline: bool,
         axes = sharding.mesh_axes(mesh)
         t0 = time.time()
         fn, in_sh, args, donate = build_cell(cfg, shape, mesh, axes)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh,
                               donate_argnums=donate).lower(*args)
             t_lower = time.time() - t0
@@ -166,4 +166,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = _FORCE_DEVICES
     main()

@@ -9,43 +9,24 @@ from __future__ import annotations
 
 import jax
 
-# ---- jax-version compat -----------------------------------------------------
-# The pinned jax (0.4.37) predates three APIs newer call sites use:
-# `jax.make_mesh(..., axis_types=...)`, `jax.sharding.set_mesh`, and the
-# top-level `jax.shard_map`.  These shims resolve to the modern API when
-# present and the 0.4.x equivalent otherwise, so the same code runs on both.
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace only
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def compat_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis_types where the kwarg exists."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh``: `jax.sharding.set_mesh` when it
-    exists, else the legacy `with mesh:` global-mesh context."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+def auto_mesh(shape, axes):
+    """`jax.make_mesh` with every axis Auto: the compiler propagates
+    shardings through gathers and scatters, as the SPMD code here expects
+    (Explicit axes, the default for a bare `make_mesh`, reject them)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
     """Small mesh over whatever devices exist (tests on CPU hosts)."""
-    return compat_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def make_shard_mesh(shards: int | None = None):
@@ -54,10 +35,9 @@ def make_shard_mesh(shards: int | None = None):
     `sgd.train_epoch_scheduled` shard_maps the D×D-blocked tier over the
     single ``"shard"`` axis (one device per col block, row blocks ring-
     rotating).  Defaults to all local devices; the trainer falls back to
-    the single-device replay when only one device exists.  Built without
-    axis_types (this jax version's `make_mesh` predates them)."""
+    the single-device replay when only one device exists."""
     shards = shards or jax.device_count()
-    return jax.make_mesh((shards,), ("shard",))
+    return auto_mesh((shards,), ("shard",))
 
 
 def serve_shard_count(request: int | str) -> int:

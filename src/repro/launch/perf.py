@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# (must precede any jax import — see dryrun.py)
-
 _DOC = """Perf hillclimbing driver (§Perf iteration loop).
 
 Re-derives the roofline terms for one (arch × shape) cell under config
@@ -16,14 +12,15 @@ Writes reports/perf/<arch>__<shape>__<tag>.json and prints the terms.
 import argparse
 import dataclasses
 import json
+import os
 import time
 
 import jax
 
 from repro.configs import base as CB
 from repro.launch import roofline as RL
-from repro.launch.dryrun import build_cell
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.dryrun import _FORCE_DEVICES, build_cell
+from repro.launch.mesh import make_production_mesh
 from repro.models import sharding
 
 
@@ -60,7 +57,7 @@ def run(arch, shape_name, overrides, tag, do_mem, multi_pod=False):
                / max(rl["t_step"], 1e-12))
     if do_mem:
         fn, in_sh, args, donate = build_cell(cfg, shape, mesh, axes)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh,
                                donate_argnums=donate).lower(*args).compile()
         ma = compiled.memory_analysis()
@@ -91,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = _FORCE_DEVICES
     main()

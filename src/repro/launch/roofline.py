@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeSpec
 from repro.launch import specs as SPECS
-from repro.launch.mesh import use_mesh
 from repro.models import lm, sharding, steps
 
 PEAK_FLOPS = 197e12
@@ -104,7 +103,7 @@ class Cost:
 
 
 def _compile_cost(fn, in_shardings, args, mesh) -> Cost:
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_shardings).lower(*args)
         compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}

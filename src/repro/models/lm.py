@@ -25,29 +25,6 @@ from repro.models import moe as MOE
 from repro.models import ssm as SSM
 
 # --------------------------------------------------------------------------
-# compat: jax 0.4.37 has no autodiff rule for lax.optimization_barrier
-# (added upstream in 0.4.38+); wrap it as a custom_vjp identity so the
-# barrier still pins scheduling on the forward pass while grads flow.
-# --------------------------------------------------------------------------
-
-
-@jax.custom_vjp
-def _opt_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-def _opt_barrier_fwd(x):
-    return _opt_barrier(x), None
-
-
-def _opt_barrier_bwd(_, ct):
-    return (ct,)
-
-
-_opt_barrier.defvjp(_opt_barrier_fwd, _opt_barrier_bwd)
-
-
-# --------------------------------------------------------------------------
 # parameter initialization (pure; dry-run uses jax.eval_shape over this)
 # --------------------------------------------------------------------------
 
@@ -249,8 +226,8 @@ def _scan_layers(body, x, stacked, cfg: ArchConfig, mesh_axes):
             # pin the FSDP all-gather of this layer's weights inside the
             # loop body — without the barrier XLA hoists gather-of-slice
             # into slice-of-(gather-of-all-layers): +40 GiB/device at 405B.
-            pl = _opt_barrier(pl)
-        carry = _opt_barrier(carry)  # save carry @ bf16
+            pl = jax.lax.optimization_barrier(pl)
+        carry = jax.lax.optimization_barrier(carry)  # save carry @ bf16
         y = body(pl, carry)
         y = L.shard_acts(y, cfg, mesh_axes) if mesh_axes else y
         return y, None

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.launch.mesh import shard_map
 
 
 def router(p, x, cfg: ArchConfig):
@@ -165,7 +164,7 @@ def moe_ffn(p, x, eid, gate, cfg: ArchConfig, mesh, mesh_axes,
     seq_axis = tp if shard_seq else None
     spec_x = P(dp, seq_axis, None)
     spec_w = P(tp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_a2a if shard_seq else local_rep, mesh=mesh,
         in_specs=(spec_x, spec_x, spec_x, spec_w, spec_w, spec_w),
         out_specs=spec_x)
@@ -237,7 +236,7 @@ def moe_ffn_ep2d(p, x, eid, gate, cfg: ArchConfig, mesh, mesh_axes,
 
     spec_x = P(dp, tp, None)
     spec_w = P(dp, None, None)   # experts over dp, replicated over tp
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_x, spec_x, spec_x, spec_w, spec_w, spec_w),
         out_specs=spec_x)

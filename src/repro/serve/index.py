@@ -254,10 +254,11 @@ def window_slices(index: LSHIndex, item_ids: jax.Array, *, cap: int):
     the item itself.  Invalid (SENTINEL / out-of-range / tail-resident)
     items get length 0.
 
-    This is the DMA contract of the `lsh_retrieve` kernel: each descriptor
-    is one static ``cap``-sized async copy out of HBM, masked to ``lens``
-    in VMEM.  A copy may therefore read up to ``cap − len`` slots past the
-    window (and, in the last band's last bucket, past the array) — consumers
+    This is the read contract of the `lsh_retrieve` path
+    (`kernels.lsh_retrieve.ref.window_pool`): each descriptor is one
+    static ``cap``-wide read of the flat id plane, masked to ``lens``.  A
+    read may therefore cover up to ``cap − len`` slots past the window
+    (and, in the last band's last bucket, past the array) — consumers
     must read ``sorted_ids`` through `padded_flat_ids`, which appends
     ``cap`` SENTINEL slots so the overrun is always in-bounds and inert.
     """

@@ -59,7 +59,7 @@ from repro.data.sparse import SparseMatrix
 from repro.kernels.candidate_score.kernel import NEG
 from repro.kernels.candidate_score.ops import score_candidates
 from repro.kernels.lsh_retrieve.kernel import lsh_retrieve_topc
-from repro.launch.mesh import make_shard_mesh, serve_shard_count, shard_map
+from repro.launch.mesh import make_shard_mesh, serve_shard_count
 from repro.resil import faults
 from repro.resil.rebuild import IndexRebuilder
 from repro.resil.validate import (PoisonBatchError, check_accumulators,
@@ -243,9 +243,10 @@ def recommend_candidates(planes: model.ServePlanes, index, sp, user_ids,
 def _pool_scores(urow, plane, cand, *, tile_b: int):
     """Scores of a [B, W] id pool with duplicates intact — tiled
     gather+einsum `lax.scan` (the candidate_score ref idiom: per-tile rows
-    stay cache-resident, no [B, W, F] cube).  SENTINEL slots score NEG."""
+    stay cache-resident, no [B, W, F] cube).  SENTINEL slots score NEG.
+    ``plane`` may carry zero lanes past b̂ (`pack_serve_planes(lanes=)`)."""
     B, W = cand.shape
-    F = plane.shape[1] - 1
+    F = urow.shape[1] - 1
 
     def tile(carry, args):
         u, c = args
@@ -397,12 +398,12 @@ def _build_sharded_recommend(mesh, *, D: int, F: int, topn: int,
             k *= 2
         return ps[None], pi[None]
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_rep, spec_rep, spec_shard, spec_shard, spec_shard,
                   spec_shard, spec_shard, spec_rep, spec_rep),
         out_specs=(spec_shard, spec_shard),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def run(row, mu, col_stack, ssig, sids, slot, n_local, bounds, sp,
@@ -495,10 +496,10 @@ class RecsysService:
                  JK: jax.Array | None = None,
                  registry: obs.Registry | None = None):
         self.params = params
-        self.planes = model.pack_serve_planes(params)   # built once
+        self.cfg = cfg
+        self.planes = self._pack(params)                # built once
         self.index = index
         self.sp = sp
-        self.cfg = cfg
         self.JK = JK if cfg.use_jk else None
         self.popular = (popular_shortlist(params, cfg.n_popular)
                         if cfg.n_popular else None)
@@ -545,6 +546,14 @@ class RecsysService:
         shards = serve_shard_count(cfg.shards) if cfg.mode != "full" else 1
         if shards > 1:
             self._init_shards(shards)
+
+    def _pack(self, params: Params) -> model.ServePlanes:
+        """Serving planes; lane-padded where the `candidate_score` kernel
+        DMAs their rows (single-device kernel path)."""
+        kernel = (self.cfg.scorer_impl() == "pallas"
+                  and serve_shard_count(self.cfg.shards) == 1)
+        lanes = 128 if kernel else 1
+        return model.pack_serve_planes(params, lanes=lanes)
 
     def _init_shards(self, shards: int) -> None:
         """Cut the item space into nnz-balanced shards and build the
@@ -610,37 +619,42 @@ class RecsysService:
         n = self.index.tail_fill
         return 0 if not n else min(self.index.tail_cap, -(-n // 16) * 16)
 
-    def _recommend(self, user_ids: jax.Array):
+    def _flush_program(self, user_ids: jax.Array):
+        """(jitted program, args, kwargs) that serve one flush of
+        ``user_ids`` on this service's configured path."""
         cfg = self.cfg
-        if cfg.mode == "full":
-            return full_topn(self.params, user_ids, topn=cfg.topn)
-        if cfg.route_full_below and self.route_decision()["decision"] == "full":
-            return full_topn(self.params, user_ids, topn=cfg.topn)
+        if cfg.mode == "full" or (
+                cfg.route_full_below
+                and self.route_decision()["decision"] == "full"):
+            return full_topn, (self.params, user_ids), dict(topn=cfg.topn)
         if self._shard_state is not None:
             sidx, col_stack, _, _ = self._shard_state
             popular = (self.popular if self.popular is not None else
                        jnp.zeros((1,), jnp.int32))
-            return self._sharded_fn(
+            return self._sharded_fn, (
                 self.planes.row, self.planes.mu, col_stack,
                 sidx.sorted_sigs, sidx.sorted_ids, sidx.slot_of,
-                sidx.n_local, sidx.bounds, self.sp, user_ids, popular)
+                sidx.n_local, sidx.bounds, self.sp, user_ids, popular), {}
         if cfg.band_budget:
             if cfg.scorer_impl() == "ref":       # CPU: pure-XLA walk path
-                return recommend_walked(
-                    self.planes, self.index, self.sp, user_ids, self.popular,
+                return recommend_walked, (
+                    self.planes, self.index, self.sp, user_ids,
+                    self.popular), dict(
                     n_seeds=cfg.n_seeds, cap=cfg.cap, budget=cfg.band_budget,
                     window=cfg.seed_window, tail_k=self._tail_k(),
                     topn=cfg.topn, tile_b=cfg.walk_tile_b)
-            return recommend_walked_kernel(
+            return recommend_walked_kernel, (
                 self.planes, self.index, self.sp, user_ids, self.popular,
-                self._flat_ids(), n_seeds=cfg.n_seeds, cap=cfg.cap, C=cfg.C,
+                self._flat_ids()), dict(
+                n_seeds=cfg.n_seeds, cap=cfg.cap, C=cfg.C,
                 window=cfg.seed_window,
                 tail_scan=self.index.tail_fill > 0, topn=cfg.topn,
                 tile_b=cfg.tile_b, interpret=cfg.interpret_mode(),
                 impl=cfg.scorer_impl())
-        return recommend_candidates(
+        return recommend_candidates, (
             self.planes, self.index, self.sp, user_ids, self.JK,
-            self.popular, n_seeds=cfg.n_seeds, cap=cfg.cap, C=cfg.C,
+            self.popular), dict(
+            n_seeds=cfg.n_seeds, cap=cfg.cap, C=cfg.C,
             window=cfg.seed_window, pool_width=cfg.resolved_pool_width(),
             fold_mates=cfg.fold_mates,
             # host-side tail mirror: an empty tail (the steady state
@@ -650,6 +664,17 @@ class RecsysService:
             tail_scan=self.index.tail_fill > 0,
             topn=cfg.topn, tile_b=cfg.tile_b,
             interpret=cfg.interpret_mode(), impl=cfg.scorer_impl())
+
+    def _recommend(self, user_ids: jax.Array):
+        fn, args, kw = self._flush_program(user_ids)
+        return fn(*args, **kw)
+
+    def flush_hlo(self) -> str:
+        """Optimized HLO of the micro-batch flush program (the one
+        `warmup` compiles) — shows which kernels the flush runs."""
+        ids = jnp.zeros((self.cfg.micro_batch,), jnp.int32)
+        fn, args, kw = self._flush_program(ids)
+        return fn.lower(*args, **kw).compile().as_text()
 
     def warmup(self):
         """Trace + compile both shapes before the timed traffic."""
@@ -1205,7 +1230,7 @@ class RecsysService:
             with self.obs.span("serve.ingest_online.swap"):
                 self.params = state.params
                 self._params_adopted = time.perf_counter()
-                self.planes = model.pack_serve_planes(state.params)
+                self.planes = self._pack(state.params)
                 self._host_bias = None     # degraded-path mirror is stale
                 self.sp = state.sp
                 if self.JK is not None:

@@ -78,6 +78,8 @@ class FitResult:
     schedule_stats: dict | None = None
     registry: obs.Registry | None = None  # the registry every timing above
                                           # was read from (ISSUE 6)
+    epoch_program: object = None  # the AOT-compiled epoch (`as_text()`
+                                  # shows which kernels it runs)
 
 
 def build_neighbours(sp: SparseMatrix, cfg: FitConfig, key):
@@ -248,4 +250,5 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
     params = to_public(state)
     return FitResult(params, JK, history, nb_secs, S, hash_key=k_sig,
                      compile_seconds=compile_secs, prep_seconds=prep_secs,
-                     schedule_stats=sched_stats, registry=reg)
+                     schedule_stats=sched_stats, registry=reg,
+                     epoch_program=epoch_fn)

@@ -17,7 +17,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
-from repro.launch.mesh import compat_mesh, shard_map, use_mesh  # noqa: E402
+from repro.launch.mesh import auto_mesh  # noqa: E402
 
 
 
@@ -155,9 +155,9 @@ def check_rotation():
     U0 = (rng.normal(size=(M, F)) * 0.1).astype(np.float32)
     V0 = (rng.normal(size=(N, F)) * 0.1).astype(np.float32)
     hp = Hyper()
-    mesh = compat_mesh((4,), ("data",))
+    mesh = auto_mesh((4,), ("data",))
     epoch_fn = make_rotation_epoch(mesh, D, M, N, hp, batch=128)
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         U1, V1 = epoch_fn(jnp.asarray(U0), jnp.asarray(V0),
                           jnp.asarray(staged["i"]), jnp.asarray(staged["j"]),
                           jnp.asarray(staged["r"]),
@@ -181,7 +181,7 @@ def check_moe_a2a():
     from repro.models import moe as MOE
     cfg = dataclasses.replace(
         CB.reduced(CB.get("dbrx-132b")), n_experts=4, moe_top_k=2)
-    mesh = compat_mesh((2, 2), ("data", "model"))
+    mesh = auto_mesh((2, 2), ("data", "model"))
     axes = {"dp": "data", "tp": "model", "ndp": 2, "ntp": 2}
     rng = np.random.default_rng(0)
     B, S, D = 4, 8, cfg.d_model
@@ -228,7 +228,7 @@ def check_moe_a2a():
 
 def check_compression():
     from repro.dist.compression import compressed_psum_mean
-    mesh = compat_mesh((4,), ("data",))
+    mesh = auto_mesh((4,), ("data",))
     rng = np.random.default_rng(0)
     g = rng.normal(size=(4, 256)).astype(np.float32)
 
@@ -236,10 +236,10 @@ def check_compression():
         m, r = compressed_psum_mean(gl[0], "data", res[0])
         return m[None], r[None]
 
-    fn = shard_map(f, mesh=mesh,
+    fn = jax.shard_map(f, mesh=mesh,
                        in_specs=(P("data", None), P("data", None)),
                        out_specs=(P("data", None), P("data", None)))
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         mean_c, resid = fn(jnp.asarray(g), jnp.zeros_like(g))
     true = g.mean(0)
     err = np.abs(np.asarray(mean_c)[0] - true).max() / np.abs(true).max()
@@ -251,7 +251,7 @@ def check_compression():
     res = jnp.zeros_like(g)
     acc = np.zeros_like(true)
     for _ in range(30):
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             m, res = fn(jnp.asarray(g), res)
         acc += np.asarray(m)[0]
     np.testing.assert_allclose(acc / 30, true, rtol=2e-3, atol=2e-4)
@@ -263,7 +263,7 @@ def check_small_dryrun():
     from repro.configs import base as CB
     from repro.launch.dryrun import build_cell
     from repro.models import sharding
-    mesh = compat_mesh((2, 2), ("data", "model"))
+    mesh = auto_mesh((2, 2), ("data", "model"))
     axes = sharding.mesh_axes(mesh)
     shape = dataclasses.replace(CB.SHAPES["train_4k"], seq_len=64,
                                 global_batch=4)
@@ -274,7 +274,7 @@ def check_small_dryrun():
         cfg = dataclasses.replace(CB.reduced(CB.get(arch)), vocab=512)
         for sh in (shape, dshape):
             fn, in_sh, args, donate = build_cell(cfg, sh, mesh, axes)
-            with use_mesh(mesh):
+            with jax.sharding.set_mesh(mesh):
                 c = jax.jit(fn, in_shardings=in_sh,
                             donate_argnums=donate).lower(*args).compile()
             assert c.cost_analysis() is not None
@@ -290,7 +290,7 @@ def check_moe_ep2d():
     cfg = dataclasses.replace(
         CB.reduced(CB.get("arctic-480b")), n_experts=4, moe_top_k=2,
         moe_dense_ff=0)
-    mesh = compat_mesh((2, 2), ("data", "model"))
+    mesh = auto_mesh((2, 2), ("data", "model"))
     axes = {"dp": "data", "tp": "model", "ndp": 2, "ntp": 2}
     rng = np.random.default_rng(0)
     B, S, D = 4, 8, cfg.d_model
@@ -330,14 +330,14 @@ def check_elastic_restore():
     from repro.train import checkpoint as ckpt
     tree = {"w": jnp.arange(64.0).reshape(8, 8),
             "b": jnp.arange(8.0)}
-    mesh4 = compat_mesh((4,), ("data",))
+    mesh4 = auto_mesh((4,), ("data",))
     sh4 = {"w": NamedSharding(mesh4, P("data", None)),
            "b": NamedSharding(mesh4, P("data"))}
     tree4 = jax.tree.map(jax.device_put, tree, sh4)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, tree4, step=1, sync=True)
         # "cluster shrinks": restore onto a 2×2 mesh with different layout
-        mesh22 = compat_mesh((2, 2), ("data", "model"))
+        mesh22 = auto_mesh((2, 2), ("data", "model"))
         sh22 = {"w": NamedSharding(mesh22, P("data", "model")),
                 "b": NamedSharding(mesh22, P("data"))}
         tree22, step = ckpt.restore(d, tree, shardings=sh22)

@@ -24,7 +24,7 @@ def test_simlsh_encode_shapes(N, deg, bits, tile):
     psi = jnp.asarray(RNG.normal(size=(N, deg)).astype(np.float32))
     phi = jnp.asarray(RNG.choice([-1., 1.], size=(N, deg, bits)).astype(np.float32))
     np.testing.assert_allclose(
-        np.asarray(simlsh_encode(psi, phi, tile_n=tile)),
+        np.asarray(simlsh_encode(psi, phi, tile_n=tile, interpret=True)),
         np.asarray(simlsh_encode_ref(psi, phi)), rtol=1e-5, atol=1e-5)
 
 
@@ -37,7 +37,7 @@ def test_neighbor_predict_shapes(B, F, K, tile, dtype):
     args = (a(B, F), a(B, F), a(B, K), a(B, K), a(B, K), a(B, K),
             a(B), a(B), a(B))
     np.testing.assert_allclose(
-        np.asarray(neighbor_predict(*args, tile_b=tile)),
+        np.asarray(neighbor_predict(*args, tile_b=tile, interpret=True)),
         np.asarray(neighbor_predict_ref(*args)), rtol=1e-4, atol=1e-4)
 
 
@@ -46,7 +46,8 @@ def test_mf_sgd_shapes(B, F, tile):
     a = lambda *s: jnp.asarray(RNG.normal(size=s).astype(np.float32))
     u, v, r = a(B, F), a(B, F), a(B)
     valid = jnp.asarray(RNG.integers(0, 2, B).astype(np.float32))
-    got = mf_sgd_step(u, v, r, valid, 0.02, 0.03, 0.01, 0.02, tile_b=tile)
+    got = mf_sgd_step(u, v, r, valid, 0.02, 0.03, 0.01, 0.02, tile_b=tile,
+                      interpret=True)
     want = mf_sgd_step_ref(u, v, r, valid, 0.02, 0.03, 0.01, 0.02)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -62,18 +63,19 @@ def test_neighbor_predict_property(B, K, seed):
     args = (a(B, F), a(B, F), a(B, K), a(B, K), a(B, K), a(B, K),
             a(B), a(B), a(B))
     np.testing.assert_allclose(
-        np.asarray(neighbor_predict(*args, tile_b=16)),
+        np.asarray(neighbor_predict(*args, tile_b=16, interpret=True)),
         np.asarray(neighbor_predict_ref(*args)), rtol=1e-4, atol=1e-4)
 
 
 def _culsh_args(B, F, K, rng):
-    """Packed-plane operands: (row [B,F+1], col [B,F+2K+1], rnb, bh_nb,
-    expl, r, valid, hp[13]) — see `mf_sgd.ref.culsh_sgd_step_ref`."""
+    """Batch-minor packed-plane operands: (row [F+1,B], col [F+2K+1,B],
+    rnb, bh_nb, expl [K,B], r, valid [B], hp[13]) — see
+    `mf_sgd.ref.culsh_sgd_step_ref`."""
     a = lambda *s: jnp.asarray(rng.normal(size=s).astype(np.float32))
-    expl = jnp.asarray(rng.integers(0, 2, (B, K)).astype(np.float32))
+    expl = jnp.asarray(rng.integers(0, 2, (K, B)).astype(np.float32))
     valid = jnp.asarray(rng.integers(0, 2, B).astype(np.float32))
     hp = jnp.concatenate([jnp.abs(a(12)) * 0.05, a(1) * 0.1])
-    return (a(B, F + 1), a(B, F + 2 * K + 1), a(B, K), a(B, K), expl,
+    return (a(F + 1, B), a(F + 2 * K + 1, B), a(K, B), a(K, B), expl,
             a(B), valid, hp)
 
 
@@ -83,7 +85,7 @@ def _culsh_args(B, F, K, rng):
 ])
 def test_culsh_sgd_shapes(B, F, K, tile, bce):
     args = _culsh_args(B, F, K, np.random.default_rng(B * 7 + K))
-    got = culsh_sgd_step(*args, tile_b=tile, bce=bce)
+    got = culsh_sgd_step(*args, tile_b=tile, bce=bce, interpret=True)
     want = culsh_sgd_step_ref(*args, bce=bce)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
@@ -93,7 +95,7 @@ def test_culsh_sgd_shapes(B, F, K, tile, bce):
 def test_culsh_sgd_invalid_rows_untouched():
     args = _culsh_args(16, 8, 4, np.random.default_rng(0))
     args = args[:6] + (jnp.zeros((16,), jnp.float32),) + args[7:]
-    row2, col2 = culsh_sgd_step(*args)
+    row2, col2 = culsh_sgd_step(*args, interpret=True)
     np.testing.assert_allclose(np.asarray(row2), np.asarray(args[0]))
     np.testing.assert_allclose(np.asarray(col2), np.asarray(args[1]))
 
@@ -102,7 +104,8 @@ def test_mf_sgd_invalid_rows_untouched():
     a = lambda *s: jnp.asarray(RNG.normal(size=s).astype(np.float32))
     u, v, r = a(16, 8), a(16, 8), a(16)
     valid = jnp.zeros((16,), jnp.float32)
-    u2, v2, e = mf_sgd_step(u, v, r, valid, 0.1, 0.1, 0.1, 0.1)
+    u2, v2, e = mf_sgd_step(u, v, r, valid, 0.1, 0.1, 0.1, 0.1,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(u2), np.asarray(u))
     np.testing.assert_allclose(np.asarray(v2), np.asarray(v))
     np.testing.assert_allclose(np.asarray(e), 0.0)
@@ -116,7 +119,7 @@ def test_ops_encode_band_matches_core(tiny_sparse):
     deg = ((maxdeg + 7) // 8) * 8
     cfg = SimLSHConfig(G=8, p=2, q=2)
     key = jax.random.PRNGKey(0)
-    S_k = encode_band(sp, cfg, key, jnp.asarray(1), deg=deg)
+    S_k = encode_band(sp, cfg, key, jnp.asarray(1), deg=deg, interpret=True)
     S_r = band_accumulate(sp.rows, sp.cols, sp.vals, key, jnp.asarray(1),
                           N=sp.N, bits=cfg.sig_bits, psi_pow=cfg.psi_pow)
     np.testing.assert_allclose(np.asarray(S_k), np.asarray(S_r),
@@ -132,7 +135,7 @@ def test_ops_predict_matches_model(tiny_sparse):
     JK = jnp.asarray(RNG.integers(0, sp.N, (sp.N, 4)), jnp.int32)
     idx = jnp.arange(256, dtype=jnp.int32)
     bt = assemble(sp, JK, idx, jnp.ones((256,), bool))
-    got = predict_batch(p, bt)
+    got = predict_batch(p, bt, interpret=True)
     want, _ = model.predict(p, bt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)

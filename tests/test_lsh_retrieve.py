@@ -103,7 +103,7 @@ def test_kernel_matches_ref_sweep(indexed, indexed_tail, n_seeds, cap, C,
         sp, index, B=12, n_seeds=n_seeds, cap=cap, tail=tail)
     exclude = jnp.asarray(list(excl) or [SENTINEL], jnp.int32)
     got = lsh_retrieve_topc(starts, lens, extra, ids_flat, exclude,
-                            C=C, cap=cap)
+                            C=C, cap=cap, interpret=True)
     want = lsh_retrieve_topc_ref(starts, lens, extra, ids_flat, exclude,
                                  C=C, cap=cap)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -120,7 +120,7 @@ def test_kernel_property_unique_subset_excluded(indexed, indexed_tail, tail):
     exclude = jnp.asarray([2, 5, 41], jnp.int32)
     C = 64
     got = np.asarray(lsh_retrieve_topc(starts, lens, extra, ids_flat,
-                                       exclude, C=C, cap=8))
+                                       exclude, C=C, cap=8, interpret=True))
     pools = _pool_sets(starts, lens, extra, ids_flat)
     for u in range(16):
         ids = got[u][got[u] != SENTINEL]
@@ -142,7 +142,7 @@ def test_retrieve_candidates_impls_agree_and_reserve_popular(
     users = jnp.arange(12, dtype=jnp.int32)
     popular = jnp.asarray([2, 11, 17], jnp.int32)
     kw = dict(n_seeds=4, cap=8, C=48, popular=popular, window=32,
-              tail_scan=tail)
+              tail_scan=tail, interpret=True)
     a = np.asarray(retrieve_candidates(index, sp, users, impl="pallas", **kw))
     b = np.asarray(retrieve_candidates(index, sp, users, impl="ref", **kw))
     np.testing.assert_array_equal(a, b)

@@ -205,7 +205,8 @@ class TestMergeTopn:
         for d in range(D):
             assert_topn_equal(parts[d][0], parts[d][1], ref_s, ref_i)
 
-    @settings(max_examples=25)
+    # no deadline: the first example pays the merge's jit compile
+    @settings(max_examples=25, deadline=None)
     @given(st.integers(0, 10_000), st.integers(2, 6), st.integers(1, 12))
     def test_property_random_splits(self, seed, D, topn):
         rng = np.random.default_rng(seed)
