@@ -154,14 +154,16 @@ def mf_step_packed(pp: PackedParams, bt: Batch, hp: Hyper, decay,
     touching only the U/V columns.  Bit-identical to `mf_step` (same
     delta computation on the same gathered values)."""
     F = pp.F
-    ui = pp.row[bt.i, :F]
-    vj = pp.col[bt.j, :F]
+    with jax.named_scope("gather"):
+        ui = pp.row[bt.i, :F]
+        vj = pp.col[bt.j, :F]
     e = _error(bt.r, jnp.sum(ui * vj, 1), bce) * bt.valid
     _, _, si_c, sj_c = _batch_scales(pp.row.shape[0], pp.col.shape[0], bt,
                                      conflict_free, scales)
     du, dv = _mf_deltas(bt, e, ui, vj, hp, decay, si_c, sj_c)
-    return dataclasses.replace(pp, row=pp.row.at[bt.i, :F].add(du),
-                               col=pp.col.at[bt.j, :F].add(dv))
+    with jax.named_scope("scatter"):
+        return dataclasses.replace(pp, row=pp.row.at[bt.i, :F].add(du),
+                                   col=pp.col.at[bt.j, :F].add(dv))
 
 
 def culsh_step(p: Params, bt: Batch, hp: Hyper, decay,
@@ -204,12 +206,13 @@ def culsh_step_packed(pp: PackedParams, bt: Batch, hp: Hyper, decay,
     normalizers (`EpochSchedule.lo_scale_*`) for the scheduled leftover
     batches; ``bh_nb`` is the shard-tier epoch-start b̂ snapshot gather."""
     F, K = pp.F, pp.K
-    row = pp.row[bt.i]                                     # [B, F+1]
-    col = pp.col[bt.j]                                     # [B, F+2K+1]
+    with jax.named_scope("gather"):
+        row = pp.row[bt.i]                                 # [B, F+1]
+        col = pp.col[bt.j]                                 # [B, F+2K+1]
+        bh_of_nb = pp.col[bt.nb, F + 2 * K] if bh_nb is None else bh_nb
     ui, b_i = row[:, :F], row[:, F]
     vj, wj = col[:, :F], col[:, F:F + K]
     cj, bh_j = col[:, F + K:F + 2 * K], col[:, F + 2 * K]
-    bh_of_nb = pp.col[bt.nb, F + 2 * K] if bh_nb is None else bh_nb
     pred, aux = predict_gathered(pp.mu, b_i, bh_j, ui, vj, wj, cj,
                                  bh_of_nb, bt.rnb, bt.expl, bt.impl)
     e = _error(bt.r, pred, bce) * bt.valid
@@ -217,11 +220,13 @@ def culsh_step_packed(pp: PackedParams, bt: Batch, hp: Hyper, decay,
                                        conflict_free, scales)
     db, dbh, du, dv, dw, dc = _culsh_deltas(
         bt, e, aux, b_i, bh_j, ui, vj, wj, cj, hp, decay, si, sj, si_c, sj_c)
-    return dataclasses.replace(
-        pp,
-        row=pp.row.at[bt.i].add(jnp.concatenate([du, db[:, None]], axis=1)),
-        col=pp.col.at[bt.j].add(
-            jnp.concatenate([dv, dw, dc, dbh[:, None]], axis=1)))
+    with jax.named_scope("scatter"):
+        return dataclasses.replace(
+            pp,
+            row=pp.row.at[bt.i].add(jnp.concatenate([du, db[:, None]],
+                                                    axis=1)),
+            col=pp.col.at[bt.j].add(
+                jnp.concatenate([dv, dw, dc, dbh[:, None]], axis=1)))
 
 
 @partial(jax.jit, static_argnames=("batch", "mf_only", "bce"),
@@ -469,37 +474,45 @@ def train_epoch_scheduled(pp: PackedParams, sd: ScheduledData,
     kw = dict(mf_only=mf_only, bce=bce, use_kernels=use_kernels, impl=impl,
               interpret=interpret)
 
+    # named scopes (device-side names in a profile, no change to the
+    # program): one per tier, and gather / kernel / scatter in each step
     if sched.shard_span:
         if shd is None:
             raise ValueError("schedule has a shard tier — pass "
                              "shd=model.build_shard_data(...)")
-        shd_p, valid_p = _shard_round_shuffle(shd, sched, keys[0])
-        if mesh is not None:
-            pp = _sharded_tier(pp, shd_p, valid_p, sched, hp, decay, mesh,
-                               mf_only=mf_only, bce=bce)
-        else:
-            # same cells, same (s, r, d) order, same b̂ snapshot → parity
-            pp = _shard_replay(pp, shd_p, valid_p, sched, hp, decay,
-                               mf_only=mf_only, bce=bce)
+        with jax.named_scope("shard_tier"):
+            shd_p, valid_p = _shard_round_shuffle(shd, sched, keys[0])
+            if mesh is not None:
+                pp = _sharded_tier(pp, shd_p, valid_p, sched, hp, decay,
+                                   mesh, mf_only=mf_only, bce=bce)
+            else:
+                # same cells, same (s, r, d) order, same b̂ snapshot →
+                # parity
+                pp = _shard_replay(pp, shd_p, valid_p, sched, hp, decay,
+                                   mf_only=mf_only, bce=bce)
 
     for t, (starts, valid) in enumerate(zip(sched.tier_starts,
                                             sched.tier_valid)):
         if not starts.shape[0]:
             continue
-        order = jax.random.permutation(keys[2 + t], starts.shape[0])
-        # tile_b passes through unclamped: the kernels fit the tile to the
-        # batch themselves (`_clamp_tile`, `_lane_tile`), which a min()
-        # against a non-power-of-two tier width would defeat
-        pp = _cf_scan(pp, sd, starts[order], valid[order], hp, decay,
-                      width=sched.widths[t], conflict_free=True,
-                      tile_b=tile_b, **kw)
+        with jax.named_scope(f"tier{t}_w{sched.widths[t]}"):
+            order = jax.random.permutation(keys[2 + t], starts.shape[0])
+            # tile_b passes through unclamped: the kernels fit the tile to
+            # the batch themselves (`_clamp_tile`, `_lane_tile`), which a
+            # min() against a non-power-of-two tier width would defeat
+            pp = _cf_scan(pp, sd, starts[order], valid[order], hp, decay,
+                          width=sched.widths[t], conflict_free=True,
+                          tile_b=tile_b, **kw)
 
     if sched.lo_starts.shape[0]:
-        order = jax.random.permutation(keys[1], sched.lo_starts.shape[0])
-        pp = _cf_scan(pp, sd, sched.lo_starts[order], sched.lo_valid[order],
-                      hp, decay, width=sched.widths[0], conflict_free=False,
-                      tile_b=tile_b,
-                      scales=(sched.lo_scale_i[order],
-                              sched.lo_scale_j[order]),
-                      **kw | dict(use_kernels=False))
+        with jax.named_scope("leftovers"):
+            order = jax.random.permutation(keys[1],
+                                           sched.lo_starts.shape[0])
+            pp = _cf_scan(pp, sd, sched.lo_starts[order],
+                          sched.lo_valid[order], hp, decay,
+                          width=sched.widths[0], conflict_free=False,
+                          tile_b=tile_b,
+                          scales=(sched.lo_scale_i[order],
+                                  sched.lo_scale_j[order]),
+                          **kw | dict(use_kernels=False))
     return pp

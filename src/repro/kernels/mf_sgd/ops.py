@@ -40,19 +40,22 @@ def apply_mf_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
     """CUSGD++ step applied to the packed planes via a conflict-free batch
     (only the U/V columns are touched)."""
     F = pp.F
-    u = pp.row[bt.i, :F]
-    v = pp.col[bt.j, :F]
+    with jax.named_scope("gather"):
+        u = pp.row[bt.i, :F]
+        v = pp.col[bt.j, :F]
     args = (u, v, bt.r, bt.valid,
             jnp.float32(hp.a_u) * decay, jnp.float32(hp.a_v) * decay,
             jnp.float32(hp.l_u), jnp.float32(hp.l_v))
-    if impl == "ref":
-        u2, v2, _ = mf_sgd_step_ref(*args, bce=bce)
-    else:
-        u2, v2, _ = mf_sgd_step(*args, tile_b=tile_b, interpret=interpret,
-                                bce=bce)
-    return dataclasses.replace(
-        pp, row=pp.row.at[bt.i, :F].add(u2 - u),
-        col=pp.col.at[bt.j, :F].add(v2 - v))
+    with jax.named_scope("kernel"):
+        if impl == "ref":
+            u2, v2, _ = mf_sgd_step_ref(*args, bce=bce)
+        else:
+            u2, v2, _ = mf_sgd_step(*args, tile_b=tile_b,
+                                    interpret=interpret, bce=bce)
+    with jax.named_scope("scatter"):
+        return dataclasses.replace(
+            pp, row=pp.row.at[bt.i, :F].add(u2 - u),
+            col=pp.col.at[bt.j, :F].add(v2 - v))
 
 
 def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
@@ -69,10 +72,11 @@ def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
     # the kernel takes batch-minor tiles: the transposes of the [B, K]
     # batch planes cancel the ones `model.slice_batch` makes, so the
     # schedule's [K, P] planes reach the kernel without a re-layout
-    row = pp.row[bt.i].T                    # [F+1, B]
-    col = pp.col[bt.j].T                    # [F+2K+1, B]
-    nb = bt.nb.T                            # [K, B]
-    bh_nb = pp.col[nb, F + 2 * K]
+    with jax.named_scope("gather"):
+        row = pp.row[bt.i].T                # [F+1, B]
+        col = pp.col[bt.j].T                # [F+2K+1, B]
+        nb = bt.nb.T                        # [K, B]
+        bh_nb = pp.col[nb, F + 2 * K]
     d = decay
     hpv = jnp.stack([hp.a_b * d, hp.a_bh * d, hp.a_u * d, hp.a_v * d,
                      hp.a_w * d, hp.a_c * d,
@@ -81,8 +85,10 @@ def apply_culsh_sgd(pp: PackedParams, bt: Batch, hp, decay, *,
                      jnp.float32(hp.l_w), jnp.float32(hp.l_c), pp.mu])
     step = (culsh_sgd_step_ref if impl == "ref"
             else partial(culsh_sgd_step, tile_b=tile_b, interpret=interpret))
-    row2, col2 = step(row, col, bt.rnb.T, bh_nb, bt.expl.T, bt.r, bt.valid,
-                      hpv, bce=bce)
-    return dataclasses.replace(
-        pp, row=pp.row.at[bt.i].add((row2 - row).T),
-        col=pp.col.at[bt.j].add((col2 - col).T))
+    with jax.named_scope("kernel"):
+        row2, col2 = step(row, col, bt.rnb.T, bh_nb, bt.expl.T, bt.r,
+                          bt.valid, hpv, bce=bce)
+    with jax.named_scope("scatter"):
+        return dataclasses.replace(
+            pp, row=pp.row.at[bt.i].add((row2 - row).T),
+            col=pp.col.at[bt.j].add((col2 - col).T))

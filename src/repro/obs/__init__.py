@@ -28,10 +28,16 @@ Naming scheme (see docs/ARCHITECTURE.md §7): dot-separated
 `train.epoch.eval`, `online.merge`.  A span's histogram shares its name;
 counters/gauges use the same prefixes (`serve.users`,
 `serve.queue_depth`).
+
+`trace_clock(spans, host_events)` maps registry time onto a JAX profiler
+trace's clock, from the context spans recorded on both (a registry with
+``jax_annotations=True``); every reader of a device trace that needs the
+program's spans goes through it.
 """
 from __future__ import annotations
 
 from repro.obs import export as _export
+from repro.obs.clock import TraceClock, trace_clock
 from repro.obs.registry import Histogram, Registry
 
 __all__ = [
@@ -39,6 +45,7 @@ __all__ = [
     "enabled", "reset", "span", "counter_add", "gauge_set", "observe",
     "event", "snapshot", "span_durations", "chrome_trace", "write_trace",
     "events_jsonl", "write_events_jsonl", "prometheus_text",
+    "TraceClock", "trace_clock",
 ]
 
 _DEFAULT = Registry(enabled=False)

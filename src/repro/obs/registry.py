@@ -30,7 +30,8 @@ asserts the no-allocation property.
 Spans can optionally mirror into `jax.profiler.TraceAnnotation`
 (``jax_annotations=True``) so the same stage names appear on the host
 timeline of XLA device profiles captured with `jax.profiler.trace` on
-real hardware.
+real hardware; `repro.obs.clock.trace_clock` maps the registry's
+clock onto that profile's from the spans recorded on both.
 """
 from __future__ import annotations
 
@@ -156,12 +157,14 @@ class _Span:
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
+        # the annotation closes next to the registry's stamp, so the two
+        # records of this span stay twins (obs.trace_clock pairs them)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         reg = self.reg
         stack = reg._stack()
         stack.pop()
         reg._end_span(self.name, self.t0, dur, len(stack))
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
         return False
 
 
@@ -301,9 +304,10 @@ class Registry:
     def record_span(self, name: str, t0_ns: int, dur_ns: int,
                     depth: int = 0) -> None:
         """Record an externally-timed interval as a completed span — for
-        intervals that overlap or cross function boundaries (e.g. the
-        dispatch-ahead flush latency, measured dispatch → result
-        readiness while the next flush is already in flight)."""
+        intervals that overlap or cross function boundaries (e.g. a
+        dispatch-ahead flush, dispatch → the host's sync while the next
+        flush is already in flight).  Such spans never reach the
+        profiler; `repro.obs.trace_clock` places them on its clock."""
         if not self.enabled:
             return
         self._end_span(name, t0_ns, dur_ns, depth)

@@ -19,9 +19,11 @@ the same shape, so the jit cache stays warm after the first call).
 Flushes are **dispatch-ahead** (double-buffered): `_flush_one` enqueues
 flush k+1 onto the device before syncing flush k, so the host-side batch
 assembly and result copy-out of one flush overlap the device compute of
-the next.  Latency is measured per flush from dispatch to *result
-readiness* (the sync), so p50/p95 stay honest — an overlapped flush's
-latency includes any time it spent queued behind its predecessor — and
+the next.  The ``serve.flush`` span runs from dispatch to the host's
+sync, which under steady traffic comes when the next flush is
+dispatched — one fill later, not when the answer is ready; the
+per-flush spans ``serve.flush.fill`` / ``.dispatch`` / ``.sync`` split
+that wait, and `repro.obs.trace_clock` lays them over a device trace.
 QPS divides by non-overlapping busy wall-time, never double-counting the
 overlap.
 
@@ -514,7 +516,7 @@ class RecsysService:
         # the default registry is enabled.
         self.obs = registry if registry is not None else obs.Registry(
             enabled=True, mirror=obs.get())
-        # pending request chunks: (user_ids, t_submitted)
+        # pending request chunks: (user_ids, t_submitted perf_counter_ns)
         self._pending: collections.deque = collections.deque()
         self._n_pending = 0
         # dispatched-but-unsynced flushes:
@@ -693,7 +695,7 @@ class RecsysService:
         staleness instead of letting queue wait grow without limit."""
         self._poll_rebuild()
         arr = np.atleast_1d(np.asarray(user_ids, np.int32))
-        self._pending.append((arr, time.perf_counter()))
+        self._pending.append((arr, time.perf_counter_ns()))
         self._n_pending += arr.shape[0]
         if self.cfg.max_pending and self._n_pending > self.cfg.max_pending:
             self._shed_over_bound()
@@ -780,12 +782,12 @@ class RecsysService:
         if shed:
             self._shed_chunks(shed)
 
-    def _shed_expired(self, now: float) -> None:
+    def _shed_expired(self, now_ns: int) -> None:
         """Deadline shedding: queue-wait is monotone along the FIFO, so
         expired chunks are exactly the queue prefix."""
-        dl = self.cfg.deadline_s
+        dl = self.cfg.deadline_s * 1e9
         shed: list = []
-        while self._pending and now - self._pending[0][1] > dl:
+        while self._pending and now_ns - self._pending[0][1] > dl:
             a, _ = self._pending.popleft()
             self._n_pending -= a.shape[0]
             shed.append(a)
@@ -796,48 +798,62 @@ class RecsysService:
         """Dispatch one micro-batch; sync the *previous* flush only after
         this one is enqueued (double-buffered dispatch-ahead).
 
+        Spans, one each per real flush: ``serve.flush.fill`` (submit of
+        the batch's oldest request → start of its dispatch),
+        ``serve.flush.dispatch`` with its children ``.take`` (pop, join
+        and pad the queue) and ``.launch`` (host → device copy and
+        program launch), then ``serve.flush.sync`` and ``serve.flush`` in
+        `_sync_oldest`.
+
         Resilience: expired chunks are shed *before* filling the batch
         (deadline shedding), and a hot-path failure — injected or real —
         falls back to the exact O(N) `full_topn` baseline instead of
         failing the flush (counter ``serve.fallback_full``)."""
         mb = self.cfg.micro_batch
         reg = self.obs
+        if self.cfg.deadline_s:
+            self._shed_expired(time.perf_counter_ns())
+        if not self._pending:        # everything this flush would have
+            return                   # taken was shed past its deadline
+        t_oldest = self._pending[0][1]
+        reg.record_span("serve.flush.fill", t_oldest,
+                        time.perf_counter_ns() - t_oldest)
         with reg.span("serve.flush.dispatch"):
-            # consume only as many queued arrays as one micro-batch needs —
-            # a huge submit is sliced by view, not re-concatenated per flush
-            now = time.perf_counter()
-            if self.cfg.deadline_s:
-                self._shed_expired(now)
-            chunks, n, t_last = [], 0, now
-            while self._pending and n < mb:
-                a, t_sub = self._pending.popleft()
-                reg.observe("serve.queue_wait", now - t_sub)
-                chunks.append(a)
-                n += a.shape[0]
-                t_last = t_sub
-            if not chunks:           # everything this flush would have
-                return               # taken was shed past its deadline
-            flat = chunks[0] if len(chunks) == 1 else np.concatenate(chunks)
-            take = flat[:mb]
-            if flat.size > mb:
-                # overflow comes entirely from the last chunk popped
-                self._pending.appendleft((flat[mb:], t_last))
-            n_real = take.size
-            self._n_pending -= n_real
-            reg.gauge_set("serve.queue_depth", self._n_pending)
-            if n_real < mb:  # pad the final partial batch to the jitted shape
-                take = np.concatenate([take, np.zeros(mb - n_real, np.int32)])
+            with reg.span("serve.flush.dispatch.take"):
+                # consume only as many queued arrays as one micro-batch
+                # needs — a huge submit is sliced by view, not
+                # re-concatenated per flush
+                chunks, n = [], 0
+                while self._pending and n < mb:
+                    a, t_last = self._pending.popleft()
+                    chunks.append(a)
+                    n += a.shape[0]
+                flat = (chunks[0] if len(chunks) == 1
+                        else np.concatenate(chunks))
+                take = flat[:mb]
+                if flat.size > mb:
+                    # overflow comes entirely from the last chunk popped
+                    self._pending.appendleft((flat[mb:], t_last))
+                n_real = take.size
+                self._n_pending -= n_real
+                reg.gauge_set("serve.queue_depth", self._n_pending)
+                if n_real < mb:
+                    # pad the final partial batch to the jitted shape
+                    take = np.concatenate(
+                        [take, np.zeros(mb - n_real, np.int32)])
 
             try:
                 faults.fire("serve.flush")    # before the timer: injected
                 # stalls read as queue wait, not scoring latency
-                t0_ns = time.perf_counter_ns()
-                out = self._recommend(jnp.asarray(take))  # async dispatch
+                with reg.span("serve.flush.dispatch.launch"):
+                    t0_ns = time.perf_counter_ns()
+                    out = self._recommend(jnp.asarray(take))  # async
             except Exception:  # noqa: BLE001 — degrade, never stall
                 reg.counter_add("serve.fallback_full")
-                t0_ns = time.perf_counter_ns()
-                out = full_topn(self.params, jnp.asarray(take),
-                                topn=self.cfg.topn)
+                with reg.span("serve.flush.dispatch.launch"):
+                    t0_ns = time.perf_counter_ns()
+                    out = full_topn(self.params, jnp.asarray(take),
+                                    topn=self.cfg.topn)
         self._inflight.append((take, n_real, t0_ns, out, False))
         reg.counter_add("serve.flushes")
         while len(self._inflight) > 1:
@@ -856,18 +872,21 @@ class RecsysService:
             self._results.append((take[:n_real], scores[:n_real],
                                   items[:n_real]))
             return
-        try:
-            jax.block_until_ready(items)
-        except Exception:  # noqa: BLE001 — deferred device failure:
-            # recompute through the exact baseline rather than lose a
-            # dispatched batch
-            reg.counter_add("serve.fallback_full")
-            scores, items = full_topn(self.params, jnp.asarray(take),
-                                      topn=self.cfg.topn)
-            jax.block_until_ready(items)
+        with reg.span("serve.flush.sync"):
+            try:
+                jax.block_until_ready(items)
+            except Exception:  # noqa: BLE001 — deferred device failure:
+                # recompute through the exact baseline rather than lose a
+                # dispatched batch
+                reg.counter_add("serve.fallback_full")
+                scores, items = full_topn(self.params, jnp.asarray(take),
+                                          topn=self.cfg.topn)
+                jax.block_until_ready(items)
         now_ns = time.perf_counter_ns()
-        # latency: dispatch → result readiness (includes time queued
-        # behind the previous flush); busy wall: overlap counted once
+        # dispatch → the host's sync, which under dispatch-ahead comes
+        # when the next flush is dispatched (one fill later under steady
+        # traffic), not when the answer is ready; busy wall: overlap
+        # counted once
         reg.record_span("serve.flush", t0_ns, now_ns - t0_ns)
         reg.counter_add("serve.busy_seconds",
                         (now_ns - max(self._last_ready_ns, t0_ns)) * 1e-9)

@@ -251,8 +251,81 @@ def test_service_stats_parity_with_registry(tiny_service):
         assert st[key] == pytest.approx(float(np.percentile(secs, q) * 1e3))
     assert st["queue"] == 0
     assert st["ingest_to_servable_s"] == 0.0    # no ingest yet
-    # queue-wait observations: one per consumed submit chunk
-    assert reg.hist_summary("serve.queue_wait")["count"] == 3
+    # fill wait: one span per real flush, none per request
+    assert len(reg.span_durations("serve.flush.fill")) == 3
+    assert reg.hist_summary("serve.queue_wait")["count"] == 0
+
+
+FLUSH_SPANS = ("serve.flush.fill", "serve.flush.dispatch",
+               "serve.flush.dispatch.take", "serve.flush.dispatch.launch",
+               "serve.flush.sync", "serve.flush")
+
+
+def test_service_spans_once_per_real_flush(tiny_service):
+    """Each real flush records fill, dispatch (holding take and launch),
+    sync and the flush span once; the degraded pseudo-flush of shed
+    requests, which never reaches the device, records none.  A flush's
+    fill starts at the submit of its oldest request."""
+    import dataclasses
+    params, index, sp, scfg, _, _ = tiny_service
+    reg = Registry(enabled=True)
+    svc = RecsysService(params, index, sp,
+                        dataclasses.replace(scfg, max_pending=24),
+                        registry=reg).warmup()
+    svc.submit(np.arange(10, dtype=np.int32))
+    t_oldest = svc._pending[0][1]
+    svc.submit(np.arange(10, 20, dtype=np.int32))     # flush 1 (16 users)
+    svc.submit(np.arange(40, dtype=np.int32))    # shed 20, flush 2 (16)
+    svc.flush()                                  # flush 3 (8, padded)
+    assert reg.counter("serve.flushes") == 3
+    assert reg.counter("serve.degraded_users") == 20
+    assert len(svc.take_results()) == 4          # 3 real + 1 degraded
+    by = {n: [sp_ for sp_ in reg.spans if sp_[0] == n] for n in FLUSH_SPANS}
+    for name in FLUSH_SPANS:
+        assert len(by[name]) == 3, name
+    assert by["serve.flush.fill"][0][1] == t_oldest
+    for k, (_, d0, dd, _, depth) in enumerate(by["serve.flush.dispatch"]):
+        fill = by["serve.flush.fill"][k]
+        assert fill[1] + fill[2] <= d0          # fill ends as dispatch starts
+        for child in ("serve.flush.dispatch.take",
+                      "serve.flush.dispatch.launch"):
+            _, c0, cd, _, cdepth = by[child][k]
+            assert d0 <= c0 and c0 + cd <= d0 + dd and cdepth == depth + 1
+        # the flush span starts inside launch; the sync lies after dispatch
+        assert d0 <= by["serve.flush"][k][1] <= d0 + dd
+        assert by["serve.flush.sync"][k][1] >= d0 + dd
+    assert reg.hist_summary("serve.queue_wait")["count"] == 0
+
+
+def test_trace_clock_recovers_offset_and_rate():
+    """Twins (spans on both the registry's and a profile's clock) fix
+    the map between them, though the profile covers only the middle of
+    the registry's spans; spans without a twin are left out."""
+    rng = np.random.default_rng(5)
+    rate, offset = 1.0 + 3.7e-6, -83_712_345_678_901.25
+    to_trace = lambda t: offset + rate * t
+    spans, t = [], 91_234_567_890_123            # perf_counter_ns-like
+    for k in range(400):
+        name = ("serve.flush.dispatch", "serve.flush.sync")[k % 2]
+        dur = int(rng.integers(50_000, 5_000_000))
+        spans.append((name, t, dur, 1, 0))
+        spans.append(("serve.flush", t, dur + 7, 1, 0))    # registry only
+        t += dur + int(rng.integers(10_000, 20_000_000))
+    mid = spans[300:560]
+    host = [(n, to_trace(a), to_trace(a + d)) for n, a, d, _, _ in mid
+            if n != "serve.flush"]
+    host.append(("bench.window", to_trace(mid[0][1]), to_trace(t)))
+    clk = obs.trace_clock(spans, host)
+    assert clk.twins == len(host) - 1
+    assert clk.residual_us < 1.0
+    assert abs(clk.rate - rate) < 1e-10
+    t_mid = mid[100][1]
+    assert abs(clk(t_mid) - to_trace(t_mid)) < 1.0            # ns
+    assert abs(clk.offset_ns - offset) < 1e3    # 1 µs, 25 h back at t = 0
+    placed = clk.place(mid, "serve.flush")
+    assert len(placed) == 130
+    assert abs(placed[0][0] - to_trace(mid[1][1])) < 1.0
+    assert obs.trace_clock(spans, [("bench.window", 0.0, 1.0)]) is None
 
 
 def test_sibling_services_isolated_but_spans_mirror(tiny_service):
