@@ -36,6 +36,7 @@ Two epoch drivers:
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -45,7 +46,9 @@ from repro.core.model import (Batch, PackedParams, Params, ScheduledData,
                               ShardData, assemble, predict, predict_gathered,
                               predict_mf, slice_batch)
 from repro.data.sparse import EpochSchedule, SparseMatrix, epoch_batches
-from repro.kernels.mf_sgd.ops import apply_culsh_sgd, apply_mf_sgd
+from repro.kernels.mf_sgd.ops import (apply_culsh_sgd, apply_mf_sgd,
+                                      nb_bias_vectorised,
+                                      neighbour_baselines)
 
 
 @jax.tree_util.register_dataclass
@@ -209,7 +212,8 @@ def culsh_step_packed(pp: PackedParams, bt: Batch, hp: Hyper, decay,
     with jax.named_scope("gather"):
         row = pp.row[bt.i]                                 # [B, F+1]
         col = pp.col[bt.j]                                 # [B, F+2K+1]
-        bh_of_nb = pp.col[bt.nb, F + 2 * K] if bh_nb is None else bh_nb
+        bh_of_nb = (neighbour_baselines(pp.bh, bt.nb) if bh_nb is None
+                    else bh_nb)
     ui, b_i = row[:, :F], row[:, F]
     vj, wj = col[:, :F], col[:, F:F + K]
     cj, bh_j = col[:, F + K:F + 2 * K], col[:, F + 2 * K]
@@ -258,16 +262,10 @@ def train_epoch(p: Params, sp: SparseMatrix, JK: jax.Array, key: jax.Array,
 def _cf_scan(pp: PackedParams, sd: ScheduledData, starts, valid, hp, decay, *,
              width: int, mf_only: bool, bce: bool, conflict_free: bool,
              use_kernels: bool, impl: str, interpret: bool, tile_b: int,
-             bh_nb_src: jax.Array | None = None,
              scales=None) -> PackedParams:
     """Scan one schedule tier: contiguous window assembly + packed step.
-
-    ``bh_nb_src`` (an epoch-start b̂ snapshot) switches the neighbour
-    baselines to the shard-tier stale-read semantics — the single-device
-    replay of a block-aligned tier must match `jax.shard_map` bit-for-bit,
-    and under sharding the live b̂ of other devices' col blocks simply
-    does not exist locally.  ``scales`` carries the per-batch precomputed
-    collision normalizers for the leftover tier."""
+    ``scales`` carries the per-batch precomputed collision normalizers
+    for the leftover tier."""
 
     valid = valid.astype(jnp.float32)   # once per tier, not per scan step
     xs = ((starts, valid) if scales is None
@@ -281,8 +279,7 @@ def _cf_scan(pp: PackedParams, sd: ScheduledData, starts, valid, hp, decay, *,
             s, val, si, sj = sv
             sc = (si, sj)
         bt = slice_batch(sd, s, width, val)
-        bh_nb = None if bh_nb_src is None else bh_nb_src[bt.nb]
-        if use_kernels and conflict_free and bh_nb is None:
+        if use_kernels and conflict_free:
             if mf_only:
                 p_ = apply_mf_sgd(p_, bt, hp, decay, impl=impl,
                                   tile_b=tile_b, interpret=interpret, bce=bce)
@@ -295,12 +292,29 @@ def _cf_scan(pp: PackedParams, sd: ScheduledData, starts, valid, hp, decay, *,
                                 conflict_free=conflict_free, scales=sc)
         else:
             p_ = culsh_step_packed(p_, bt, hp, decay, bce,
-                                   conflict_free=conflict_free, bh_nb=bh_nb,
-                                   scales=sc)
+                                   conflict_free=conflict_free, scales=sc)
         return p_, None
 
     pp, _ = jax.lax.scan(body, pp, xs)
     return pp
+
+
+def nb_bias_lookup_steps(sched: EpochSchedule, N: int, K: int, *,
+                         mf_only: bool, platform: str) -> int:
+    """Steps of one `train_epoch_scheduled` epoch over an ``N``-item col
+    plane, compiled for ``platform``, whose neighbour baselines take
+    `neighbour_baselines`' one-hot path, each step looking up K ids for
+    every slot of its batch."""
+    if mf_only or platform == "cpu":
+        return 0
+    cf = lambda w: nb_bias_vectorised(N, K * w)
+    steps = sum(int(s.shape[0]) for s, w in zip(sched.tier_starts,
+                                                 sched.widths) if cf(w))
+    if cf(sched.widths[0]):
+        steps += int(sched.lo_starts.shape[0])
+    if sched.shard_span and cf(sched.shard_width):
+        steps += math.prod(sched.shard_starts.shape)
+    return steps
 
 
 _SHD_FIELDS = ("i", "j", "r", "nb", "rnb", "expl")
@@ -352,7 +366,7 @@ def _shard_replay(pp: PackedParams, shd: ShardData, valid,
             p_ = mf_step_packed(p_, bt, hp, decay, bce, conflict_free=True)
         else:
             p_ = culsh_step_packed(p_, bt, hp, decay, bce, conflict_free=True,
-                                   bh_nb=bh0[bt.nb])
+                                   bh_nb=neighbour_baselines(bh0, bt.nb))
         return p_, None
 
     pp, _ = jax.lax.scan(body, pp, xs)
@@ -404,9 +418,9 @@ def _sharded_tier(pp: PackedParams, shd: ShardData, valid,
                     pl = mf_step_packed(pl, bt, hp, decay, bce,
                                         conflict_free=True)
                 else:
-                    pl = culsh_step_packed(pl, bt, hp, decay, bce,
-                                           conflict_free=True,
-                                           bh_nb=bh0[bt.nb])
+                    pl = culsh_step_packed(
+                        pl, bt, hp, decay, bce, conflict_free=True,
+                        bh_nb=neighbour_baselines(bh0, bt.nb))
                 return (pl.row, pl.col), None
             return step
 

@@ -19,7 +19,8 @@ carried *batch-minor*: one sample per lane, so its tiles are
 ``row [F+1, TB]`` = U‖b, ``col [F+2K+1, TB]`` = V‖W‖C‖b̂ and the three
 neighbour planes ``[K, TB]``.  The pallas_call carries 7 operands and 2
 outputs instead of the 15/6 of the pre-packed layout, the surrounding step
-is one gather + one delta-scatter per plane, and in-kernel the planes are
+is one gather + one delta-scatter per plane plus the lookup of the
+neighbours' b̂ (`ops.neighbour_baselines`), and in-kernel the planes are
 split with static sublane slices.  Batch-minor is what the TPU needs here:
 the schedule-ordered neighbour planes (`model.ScheduledData`) are stored
 ``[K, P]``, which tiles without padding, and a kernel operand ``[B, K]``
@@ -59,7 +60,7 @@ def _culsh_kernel(bce, row_ref, col_ref, rnb_ref, bhnb_ref, expl_ref,
     row = row_ref[...]                         # [F+1, TB] — U ‖ b
     col = col_ref[...]                         # [F+2K+1, TB] — V ‖ W ‖ C ‖ b̂
     rnb = rnb_ref[...]                         # [K, TB]
-    bh_nb = bhnb_ref[...]                      # [K, TB] — b̂[J^K[j]] gather
+    bh_nb = bhnb_ref[...]                      # [K, TB] — b̂[J^K[j]]
     expl = expl_ref[...]
     r, valid = r_ref[...], valid_ref[...]      # [1, TB]
     F = row.shape[0] - 1

@@ -31,8 +31,9 @@ def culsh_sgd_step_ref(row, col, rnb, bh_nb, expl, r, valid, hp, *,
     are ``[B]``; ``hp`` packs the 12 decayed hyper scalars
     ``(γb, γb̂, γu, γv, γw, γc, λb, λb̂, λu, λv, λw, λc)`` plus ``μ``.
     The Eq. (1) forward (including b̄, residuals and the |R|/|N|
-    normalizers) happens *inside* the step — only the neighbour-baseline
-    gather ``bh_nb`` = b̂[J^K[j]] needs the full plane and stays outside.
+    normalizers) happens *inside* the step — only the neighbour baselines
+    ``bh_nb`` = b̂[J^K[j]] need the whole b̂ column and are looked up
+    outside (`ops.neighbour_baselines`).
     Returns the two updated tiles, batch-minor; `ops.apply_culsh_sgd`
     turns them into one delta-scatter per plane.
     """

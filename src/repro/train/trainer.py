@@ -213,6 +213,9 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
                 interpret=interpret, mesh=mesh).compile()
             run = lambda qq, kk, ee: epoch_fn(qq, sd, sched, kk, ee, cfg.hp,
                                               shd=shd)
+            nb_bias_steps = sgd.nb_bias_lookup_steps(
+                sched, state.col.shape[0], state.K, mf_only=mf_only,
+                platform=jax.default_backend())
         else:
             state = params
             to_public = lambda q: q
@@ -220,6 +223,7 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
                 state, sp, JK, k0, ep0, cfg.hp, batch=cfg.batch,
                 mf_only=mf_only, bce=bce).compile()
             run = lambda qq, kk, ee: epoch_fn(qq, sp, JK, kk, ee, cfg.hp)
+            nb_bias_steps = 0
     compile_secs = reg.span_durations("train.compile")[-1]
 
     history = []
@@ -230,6 +234,8 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
             jax.block_until_ready(jax.tree.leaves(state)[0])
         t_train += reg.span_durations("train.epoch")[-1]
         reg.counter_add("train.epochs")
+        # steps whose neighbour b̂ took the vectorised lookup
+        reg.counter_add("train.nb_bias_lookup_steps", nb_bias_steps)
         if cfg.eval_every and (ep + 1) % cfg.eval_every == 0:
             with reg.span("train.epoch.eval"):
                 p_eval = to_public(state)

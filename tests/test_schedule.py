@@ -4,10 +4,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import model, sgd
 from repro.data.sparse import conflict_free_schedule, from_coo
+from repro.kernels.mf_sgd import ops
 from repro.kernels.mf_sgd.ops import apply_culsh_sgd, apply_mf_sgd
 
 RNG = np.random.default_rng(0)
@@ -364,19 +366,28 @@ def test_mf_kernel_matches_mf_step(tiny_sparse):
                                    rtol=1e-5, atol=1e-6, err_msg=impl)
 
 
+def _epoch_setup(sp, K=4):
+    """Neighbour lists, a tiered schedule with leftovers, its ordered data
+    and packed initial planes for `train_epoch_scheduled` runs."""
+    JK = jnp.asarray(np.random.default_rng(0).integers(0, sp.N, (sp.N, K)),
+                     jnp.int32)
+    sched = conflict_free_schedule(np.asarray(sp.rows), np.asarray(sp.cols),
+                                   batch=128, M=sp.M, N=sp.N, seed=0)
+    sd = model.build_scheduled_data(sp, JK, sched)
+    p0 = model.init_from_data(jax.random.PRNGKey(0), sp, 8, K)
+    return JK, sched, sd, model.pack_params(p0)
+
+
+def _copy(p):
+    return jax.tree.map(jnp.copy, p)
+
+
 def test_scheduled_epoch_learns_and_matches_unscheduled(tiny_sparse):
     """train_epoch_scheduled drops the loss like train_epoch does, and the
     kernel path is bit-identical to the jnp scheduled path on CPU."""
     sp = tiny_sparse
-    K = 4
-    JK = jnp.asarray(RNG.integers(0, sp.N, (sp.N, K)), jnp.int32)
-    sched = conflict_free_schedule(np.asarray(sp.rows), np.asarray(sp.cols),
-                                   batch=128, M=sp.M, N=sp.N, seed=0)
-    sd = model.build_scheduled_data(sp, JK, sched)
+    JK, sched, sd, pp0 = _epoch_setup(sp)
     hp = sgd.Hyper()
-    p0 = model.init_from_data(jax.random.PRNGKey(0), sp, 8, K)
-    pp0 = model.pack_params(p0)
-    copy = lambda p: jax.tree.map(jnp.copy, p)
     key = jax.random.PRNGKey(1)
 
     def sse(pp):
@@ -390,12 +401,45 @@ def test_scheduled_epoch_learns_and_matches_unscheduled(tiny_sparse):
     for ep in range(2):
         kk = jax.random.fold_in(key, ep)
         ee = jnp.asarray(ep)
-        p1 = sgd.train_epoch_scheduled(copy(pp0) if p1 is None else p1,
+        p1 = sgd.train_epoch_scheduled(_copy(pp0) if p1 is None else p1,
                                        sd, sched, kk, ee, hp)
-        p2 = sgd.train_epoch_scheduled(copy(pp0) if p2 is None else p2,
+        p2 = sgd.train_epoch_scheduled(_copy(pp0) if p2 is None else p2,
                                        sd, sched, kk, ee, hp,
                                        use_kernels=True, impl="ref")
     assert sse(p1) < base
     for l1, l2 in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                    rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("use_kernels,impl", [
+    (False, "ref"), (True, "ref"), (True, "pallas")],
+    ids=["jnp", "kernel-ref", "kernel-pallas"])
+def test_scheduled_epoch_nb_bias_lookup_bit_equal(tiny_sparse, monkeypatch,
+                                                   use_kernels, impl):
+    """An epoch whose neighbour b̂ is looked up one-hot returns planes
+    bit-equal to the epoch that gathers it: every width tier (through the
+    kernel step, or the jnp one) and the leftover tier."""
+    JK, sched, sd, pp0 = _epoch_setup(tiny_sparse)
+    assert len(sched.tier_starts) > 1 and sched.lo_starts.shape[0] > 0
+    out = {}
+    # the path is chosen as the epoch is traced, so each forced path needs
+    # a fresh trace; a CPU program would otherwise keep the gather
+    for path, per_lookup in (("gather", 0), ("onehot", 1 << 30)):
+        monkeypatch.setattr(ops, "ONEHOT_ITEMS_PER_LOOKUP", per_lookup)
+        if path == "onehot":
+            monkeypatch.setattr(ops, "_nb_bias_gather", ops._nb_bias_onehot)
+        jax.clear_caches()
+        hlo = jax.jit(lambda p: ops.neighbour_baselines(p.bh, JK)).lower(
+            pp0).compile().as_text()
+        assert (" dot(" in hlo) == (path == "onehot")
+        out[path] = sgd.train_epoch_scheduled(
+            _copy(pp0), sd, sched, jax.random.PRNGKey(3), jnp.asarray(0),
+            sgd.Hyper(), use_kernels=use_kernels, impl=impl, interpret=True)
+    monkeypatch.undo()
+    jax.clear_caches()
+    for plane in ("row", "col"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out["onehot"], plane)).view(np.int32),
+            np.asarray(getattr(out["gather"], plane)).view(np.int32),
+            err_msg=plane)

@@ -11,15 +11,19 @@ process may hold the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import sgd
+from repro.core.model import Batch, PackedParams
 from repro.kernels.candidate_score.kernel import candidate_score_topn
 from repro.kernels.lsh_retrieve.kernel import lsh_retrieve_topc
 from repro.kernels.mf_sgd.kernel import culsh_sgd_step, mf_sgd_step
+from repro.kernels.mf_sgd.ops import apply_culsh_sgd
 
 
 @pytest.fixture(scope="module")
@@ -93,3 +97,36 @@ def test_mf_sgd_compiles_wide_and_narrow_tiers(one_chip, B):
         u, v, r, val, hp[0], hp[1], hp[2], hp[3], tile_b=256,
         interpret=False),
         S(B, F), S(B, F), S(B), S(B), S(4))
+
+
+@pytest.mark.parametrize("B,kernel", [(512, True), (64, True), (512, False)],
+                         ids=["kernel-w512", "kernel-w64", "leftovers"])
+def test_culsh_step_reads_neighbour_bias_without_element_gather(
+        one_chip, B, kernel):
+    """The CULSH step at the MovieLens-10M shape (N=10,677, F=K=32) looks
+    the neighbours' b̂ up one-hot: its compiled program gathers whole
+    plane rows only — no one-element gather (``slice_sizes={1,1}`` from
+    the col plane, or ``{1}`` from b̂) — and the lookup's scope is there."""
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    F = K = 32
+    M, N = 27951, 10677
+    pp = PackedParams(row=S((M, F + 1)), col=S((N, F + 2 * K + 1)),
+                      mu=S(()), F=F, K=K)
+    ids = lambda *shape: S(shape, jnp.int32)
+    bt = Batch(i=ids(B), j=ids(B), r=S((B,)), nb=ids(B, K), rnb=S((B, K)),
+               expl=S((B, K)), impl=S((B, K)), valid=S((B,)))
+    hp = sgd.Hyper()
+    if kernel:
+        step = lambda p, b, d: apply_culsh_sgd(
+            p, b, hp, d, impl="pallas", tile_b=256, interpret=False)
+        txt = _compile(step, pp, bt, S(()))
+    else:   # the leftover tier: scaled jnp step, precomputed normalizers
+        step = lambda p, b, d, si, sj: sgd.culsh_step_packed(
+            p, b, hp, d, scales=(si, sj))
+        txt = jax.jit(step).lower(pp, bt, S(()), S((B,)), S((B,))) \
+            .compile().as_text()
+    sizes = re.findall(r" gather\(.*?slice_sizes=\{([0-9,]+)\}", txt)
+    assert sizes, "no plane-row gather found"
+    assert not {"1,1", "1"} & set(sizes), sizes
+    assert "gather/nb_bias/" in txt
