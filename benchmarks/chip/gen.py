@@ -7,7 +7,10 @@ onto the device, of generators the repository keeps elsewhere:
 * `ratings` — the MovieLens-shaped ratings of `repro.data.synthetic.
   generate`: zipf popularity on both sides, unique (user, item) pairs, a
   planted rank-8 signal with item groups that share a latent direction,
-  ratings squashed into [rmin, rmax];
+  ratings squashed into [rmin, rmax].  It holds for any M and N up to a
+  few million each, M·N past 2³¹ included: pairs are told apart by a sort
+  on (row, col), never by a flat int32 key, and every id is drawn at its
+  Zipf share (`_zipf_draw`);
 * `catalog` — the planted-group catalog of `benchmarks/bench_serve.
   make_catalog`: items in groups of 50, users in groups of 32, each item
   rated by `deg` distinct users of its own group; one catalog for every
@@ -20,6 +23,7 @@ much work the window holds.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -35,27 +39,92 @@ def key_of(seed: int, salt: int) -> jax.Array:
                               salt)
 
 
+# The float32 inverse CDF gives an id its share only while that share spans
+# a few steps of the uniform draw's 2⁻²³ grid and of the CDF's ulps near 1;
+# below 2⁻²¹ of the mass the tail's steps coarsen, then vanish.
+_FINE = 2.0 ** -21
+
+
 def _zipf_cdf(n: int, a: float) -> jax.Array:
     p = 1.0 / jnp.arange(1, n + 1, dtype=jnp.float32) ** a
     return jnp.cumsum(p) / jnp.sum(p)
 
 
+def _zipf_p(n: int, a: float) -> np.ndarray:
+    return np.arange(1, n + 1, dtype=np.float64) ** -a
+
+
+def _zipf_blocks(n: int, a: float):
+    """Two-level inverse CDF of Zipf(a) over n ids → (outer [nb], inner
+    [nb·B], B): the CDF over blocks of B ids (a power of two ≥ √n), then
+    each block's own CDF, both summed in float64 and rounded to float32
+    (padding past id n reads 1.0).  Every id's step at its level is at
+    least `_FINE`."""
+    B = 1 << math.ceil(math.log2(math.sqrt(n)))
+    nb = -(-n // B)
+    p = np.zeros(nb * B)
+    p[:n] = _zipf_p(n, a)
+    p = p.reshape(nb, B)
+    mass = p.sum(1)
+    outer = np.cumsum(mass) / mass.sum()
+    inner = np.cumsum(p, 1) / mass[:, None]
+    outer[-1] = 1.0
+    inner[:, -1] = 1.0
+    inner.reshape(-1)[n:] = 1.0
+    outer, inner = outer.astype(np.float32), inner.astype(np.float32)
+    steps = np.diff(inner, axis=1, prepend=0.0).reshape(-1)[:n]
+    if min(np.diff(outer, prepend=0.0).min(), steps.min()) < _FINE:
+        raise ValueError(f"Zipf({a}) over {n} ids is past what two float32 "
+                         "levels resolve")
+    return outer, inner.reshape(-1), B
+
+
+def _zipf_draw(key, n: int, a: float, take: int) -> jax.Array:
+    """``take`` ids in [0, n) with P(j) ∝ (j + 1)^−a.  Where every id's
+    share is at least `_FINE`, one float32 inverse CDF; past that, the
+    two-level one of `_zipf_blocks`, which reaches every id and leaves the
+    last no excess.  The choice rests on (n, a) alone."""
+    p = _zipf_p(n, a)
+    if p[-1] / p.sum() >= _FINE:
+        return jnp.searchsorted(_zipf_cdf(n, a),
+                                jax.random.uniform(key, (take,)))
+    outer, inner, B = _zipf_blocks(n, a)
+    return _two_level(key, jnp.asarray(outer), jnp.asarray(inner), B, take)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _two_level(key, outer, inner, B: int, take: int):
+    u = jax.random.uniform(key, (2, take))
+    base = jnp.minimum(jnp.searchsorted(outer, u[0]),
+                       outer.shape[0] - 1).astype(jnp.int32) * B
+    # lower bound of u[1] in the block's CDF, whose last entry is 1.0 > u
+    pos = jnp.zeros_like(base)
+    step = B // 2
+    while step:
+        pos = jnp.where(inner[base + pos + step - 1] < u[1], pos + step, pos)
+        step //= 2
+    return base + pos
+
+
 def _draw_pairs(key, M: int, N: int, take: int, a: float):
     ku, ki = jax.random.split(key)
-    r = jnp.searchsorted(_zipf_cdf(M, a), jax.random.uniform(ku, (take,)))
-    c = jnp.searchsorted(_zipf_cdf(N, a), jax.random.uniform(ki, (take,)))
+    r = _zipf_draw(ku, M, a, take)
+    c = _zipf_draw(ki, N, a, take)
     return (jnp.minimum(r, M - 1).astype(jnp.int32),
             jnp.minimum(c, N - 1).astype(jnp.int32))
 
 
-def _unique_keys(key, rows, cols, N: int, nnz: int):
-    """``nnz`` distinct (row, col) keys drawn uniformly from the distinct
-    keys in ``rows``/``cols`` (M·N < 2³¹ for every configuration here)."""
-    k = jnp.sort(rows * N + cols)
-    first = jnp.concatenate([jnp.ones((1,), bool), k[1:] != k[:-1]])
-    prio = jnp.where(first, jax.random.uniform(key, k.shape), 2.0)
+def _unique_pairs(key, rows, cols, nnz: int):
+    """``nnz`` distinct (row, col) pairs drawn uniformly from the distinct
+    pairs in ``rows``/``cols``, and how many there were.  The pairs are
+    sorted by (row, col), which is the order of ``row · N + col`` without
+    forming it, so M·N may pass 2³¹."""
+    r, c = jax.lax.sort((rows, cols), num_keys=2)
+    first = jnp.concatenate([jnp.ones((1,), bool),
+                             (r[1:] != r[:-1]) | (c[1:] != c[:-1])])
+    prio = jnp.where(first, jax.random.uniform(key, r.shape), 2.0)
     pick = jnp.argsort(prio)[:nnz]
-    return k[pick], jnp.sum(first)
+    return r[pick], c[pick], jnp.sum(first)
 
 
 def ratings(cfg: dict, seed: int):
@@ -72,15 +141,15 @@ def ratings(cfg: dict, seed: int):
     for attempt in range(8):
         rows, cols = _draw_pairs(jax.random.fold_in(k_draw, attempt), M, N,
                                  take, a)
-        keys, distinct = jax.jit(_unique_keys, static_argnums=(3, 4))(
-            k_pick, rows, cols, N, nnz)
+        rows, cols, distinct = jax.jit(_unique_pairs, static_argnums=3)(
+            k_pick, rows, cols, nnz)
         if int(distinct) >= nnz:
             break
         take = int(take * 1.6)
     else:
         raise RuntimeError(f"could not draw {nnz} distinct pairs")
     rows, cols, vals, group = _planted_values(
-        k_fac, keys, M, N, F, cfg["groups"] or max(4, N // 50),
+        k_fac, rows, cols, M, N, F, cfg["groups"] or max(4, N // 50),
         cfg["noise"], cfg["rmin"], cfg["rmax"])
     perm = jax.random.permutation(k_split, nnz)
     n_test = int(nnz * cfg["test_frac"])
@@ -91,11 +160,10 @@ def ratings(cfg: dict, seed: int):
     return train, test, group
 
 
-def _planted_values(key, keys, M, N, F, G, noise, rmin, rmax):
+def _planted_values(key, rows, cols, M, N, F, G, noise, rmin, rmax):
     @jax.jit
-    def make(key, keys):
+    def make(key, rows, cols):
         ks = jax.random.split(key, 7)
-        rows, cols = keys // N, keys % N
         group = jax.random.randint(ks[0], (N,), 0, G)
         s = 1.0 / math.sqrt(F)
         u = jax.random.normal(ks[1], (M, F)) * s
@@ -108,8 +176,8 @@ def _planted_values(key, keys, M, N, F, G, noise, rmin, rmax):
                + jax.random.normal(ks[6], rows.shape) * noise)
         mid, amp = 0.5 * (rmin + rmax), 0.5 * (rmax - rmin)
         vals = jnp.clip(mid + amp * jnp.tanh(raw), rmin, rmax)
-        return rows.astype(jnp.int32), cols.astype(jnp.int32), vals, group
-    return make(key, keys)
+        return rows, cols, vals, group
+    return make(key, rows, cols)
 
 
 def catalog(cfg: dict, seed: int):

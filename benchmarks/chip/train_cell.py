@@ -25,10 +25,14 @@ the last of those epochs from the reference's; ``late_gain_gap``, how
 much less `fit`'s RMSE fell than the reference's over the last quarter of
 them; and ``eval_gap``, `fit`'s last reported RMSE against the
 reference's evaluation of the model `fit` returned (its parameters and
-neighbour lists).  The later epochs are the ones that tell: the Eq. (7)
-decay shrinks their steps below what a lower precision resolves, while
-the program's early lag behind the reference (its leftover batches
-average their collisions) closes there.
+neighbour lists).  The reference's ``dense`` returns whatever structure of
+the training ratings its ``rmse`` reads: for ``ml10m-culsh`` the dense
+[M, N] matrix, which a configuration with a large catalog cannot have (a
+15,641 × 624,961 share would take 39 GB in float32), so such a
+configuration's reference keeps its ratings sparse.  The later epochs
+are the ones that tell: the Eq. (7) decay shrinks their steps below what
+a lower precision resolves, while the program's early lag behind the
+reference (its leftover batches average their collisions) closes there.
 """
 from __future__ import annotations
 
