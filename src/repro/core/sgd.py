@@ -54,6 +54,24 @@ from repro.kernels.mf_sgd.ops import (apply_culsh_sgd, apply_mf_sgd,
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class Hyper:
+    """Eq. (5) step sizes α, regularisation λ and the Eq. (7) decay β,
+    as stated for ratings on a 1–5 scale.
+
+    `trainer.fit` maps them to the scale of its training ratings
+    (`rating_scale`, `for_rating_scale`): with s the smallest power of
+    two ≥ (max − min) / 4 of the ratings, at least 1, it trains with
+    a_u, a_v ÷ s and l_u, l_v × s, a_w ÷ s² and l_w × s², every other
+    step, λ and β as stated.  Eq. (5) on the ratings r then follows the
+    path of Eq. (5) on r / s under the stated steps, its parameters
+    mapped as U, V × √s; μ, b, b̂, C × s; W unchanged: the prediction
+    and the error scale by s, so the b, b̂ and C steps (linear in e and
+    in their own parameter) need nothing, the U, V steps (e·V scales by
+    s√s) need α ÷ s, the W step (e·residual scales by s²) needs α ÷ s²,
+    and each λ rises as its α falls, so every α·λ is unchanged.  A power
+    of two keeps the mapped steps exact in float, and 1–5 data (s = 1)
+    trains with the stated steps bit for bit.  The online updater and
+    the loop use the steps they are given, unmapped.
+    """
     # initial learning rates (paper Table 3/5 names)
     a_b: float = 0.02
     a_bh: float = 0.02
@@ -70,6 +88,26 @@ class Hyper:
     l_c: float = 0.05
     # Eq. (7) decay
     beta: float = 0.3
+
+    def for_rating_scale(self, s: int) -> "Hyper":
+        """The steps for ratings ``s`` times the stated scale (see the
+        class docstring); ``s`` a power of two, so the result is exact."""
+        if s == 1:
+            return self
+        return dataclasses.replace(
+            self, a_u=self.a_u / s, a_v=self.a_v / s, l_u=self.l_u * s,
+            l_v=self.l_v * s, a_w=self.a_w / s ** 2, l_w=self.l_w * s ** 2)
+
+
+def rating_scale(vals) -> int:
+    """s: the smallest power of two ≥ (max − min) / 4 of ``vals``, at
+    least 1 (1 on any 1–5 data, 32 on 0–100)."""
+    vals = jnp.asarray(vals)
+    span = float(jnp.max(vals) - jnp.min(vals)) / 4 if vals.size else 0.0
+    s = 1
+    while s < span:
+        s *= 2
+    return s
 
 
 def lr_decay(hp: Hyper, t: jax.Array) -> jax.Array:
@@ -299,6 +337,19 @@ def _cf_scan(pp: PackedParams, sd: ScheduledData, starts, valid, hp, decay, *,
     return pp
 
 
+def _epoch_steps(sched: EpochSchedule):
+    """(batch width, steps) of each part of one `train_epoch_scheduled`
+    epoch: the width tiers, the leftovers (at the widest width) and the
+    shard tier's cells."""
+    parts = list(zip(sched.widths, (int(s.shape[0])
+                                    for s in sched.tier_starts)))
+    parts.append((sched.widths[0], int(sched.lo_starts.shape[0])))
+    if sched.shard_span:
+        parts.append((sched.shard_width,
+                      math.prod(sched.shard_starts.shape)))
+    return parts
+
+
 def nb_bias_lookup_steps(sched: EpochSchedule, N: int, K: int, *,
                          mf_only: bool, platform: str) -> int:
     """Steps of one `train_epoch_scheduled` epoch over an ``N``-item col
@@ -307,14 +358,18 @@ def nb_bias_lookup_steps(sched: EpochSchedule, N: int, K: int, *,
     every slot of its batch."""
     if mf_only or platform == "cpu":
         return 0
-    cf = lambda w: nb_bias_vectorised(N, K * w)
-    steps = sum(int(s.shape[0]) for s, w in zip(sched.tier_starts,
-                                                 sched.widths) if cf(w))
-    if cf(sched.widths[0]):
-        steps += int(sched.lo_starts.shape[0])
-    if sched.shard_span and cf(sched.shard_width):
-        steps += math.prod(sched.shard_starts.shape)
-    return steps
+    return sum(n for w, n in _epoch_steps(sched)
+               if nb_bias_vectorised(N, K * w))
+
+
+def nb_bias_gather_steps(sched: EpochSchedule, N: int, K: int, *,
+                         mf_only: bool, platform: str) -> int:
+    """Steps of the same epoch whose neighbour baselines take the gather:
+    every CULSH step that `nb_bias_lookup_steps` does not count."""
+    if mf_only:
+        return 0
+    return sum(n for _, n in _epoch_steps(sched)) - nb_bias_lookup_steps(
+        sched, N, K, mf_only=mf_only, platform=platform)
 
 
 _SHD_FIELDS = ("i", "j", "r", "nb", "rnb", "expl")

@@ -82,16 +82,25 @@ class FitResult:
                                   # shows which kernels it runs)
 
 
-def build_neighbours(sp: SparseMatrix, cfg: FitConfig, key):
-    """Neighbour search stage — (JK or None, seconds, S or None, sig key)."""
+def build_neighbours(sp: SparseMatrix, cfg: FitConfig, key,
+                     registry: obs.Registry | None = None):
+    """Neighbour search stage — (JK or None, seconds, S or None, sig key).
+    simLSH's two parts are timed in ``registry``'s spans
+    ``train.neighbours.encode`` and ``train.neighbours.topk``."""
+    reg = registry if registry is not None else obs.get()
     t0 = time.perf_counter()
     S = None
     k_sig, k_top = jax.random.split(key)
     if cfg.method == "none":
         return None, 0.0, None, k_sig
     if cfg.method == "simlsh":
-        sigs, S = simlsh.encode(sp, cfg.lsh, k_sig, return_accumulators=True)
-        JK = topk.topk_from_signatures(sigs, k_top, K=cfg.K, band_cap=cfg.lsh.band_cap)
+        with reg.span("train.neighbours.encode"):
+            sigs, S = simlsh.encode(sp, cfg.lsh, k_sig,
+                                    return_accumulators=True)
+            jax.block_until_ready(sigs)
+        with reg.span("train.neighbours.topk"):
+            JK = jax.block_until_ready(topk.topk_from_signatures(
+                sigs, k_top, K=cfg.K, band_cap=cfg.lsh.band_cap))
     elif cfg.method == "gsm":
         JK = gsm.gsm_topk(sp, K=cfg.K)
     elif cfg.method == "rand":
@@ -122,9 +131,15 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
     k_nb, k_init, k_ep = jax.random.split(key, 3)
     sp = from_coo(*train_coo, shape)
     te_r, te_c, te_v = (jnp.asarray(a) for a in test_coo)
+    # steps for the ratings' own scale (`sgd.Hyper`): cfg.hp on 1–5 data
+    scale = sgd.rating_scale(sp.vals)
+    hp = cfg.hp.for_rating_scale(scale)
+    reg.counter_add("train.rating_scale", scale)
+    if log:
+        log(f"rating scale: s = {scale} (steps a_u, a_v / s, a_w / s^2)")
 
     with reg.span("train.neighbours"):
-        JK, _, S, k_sig = build_neighbours(sp, cfg, k_nb)
+        JK, _, S, k_sig = build_neighbours(sp, cfg, k_nb, reg)
     nb_secs = reg.span_durations("train.neighbours")[-1]
     mf_only = cfg.method == "none"
     if JK is None:  # plain MF still needs a JK placeholder for batch assembly
@@ -208,22 +223,25 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
             to_public = lambda q: model.unmap_params(model.unpack_params(q),
                                                      sched)
             epoch_fn = sgd.train_epoch_scheduled.lower(
-                state, sd, sched, k0, ep0, cfg.hp, shd=shd, mf_only=mf_only,
+                state, sd, sched, k0, ep0, hp, shd=shd, mf_only=mf_only,
                 bce=bce, use_kernels=cfg.use_kernels, impl=impl,
                 interpret=interpret, mesh=mesh).compile()
-            run = lambda qq, kk, ee: epoch_fn(qq, sd, sched, kk, ee, cfg.hp,
+            run = lambda qq, kk, ee: epoch_fn(qq, sd, sched, kk, ee, hp,
                                               shd=shd)
+            nb_kw = dict(mf_only=mf_only, platform=jax.default_backend())
             nb_bias_steps = sgd.nb_bias_lookup_steps(
-                sched, state.col.shape[0], state.K, mf_only=mf_only,
-                platform=jax.default_backend())
+                sched, state.col.shape[0], state.K, **nb_kw)
+            nb_gather_steps = sgd.nb_bias_gather_steps(
+                sched, state.col.shape[0], state.K, **nb_kw)
         else:
             state = params
             to_public = lambda q: q
             epoch_fn = sgd.train_epoch.lower(
-                state, sp, JK, k0, ep0, cfg.hp, batch=cfg.batch,
+                state, sp, JK, k0, ep0, hp, batch=cfg.batch,
                 mf_only=mf_only, bce=bce).compile()
-            run = lambda qq, kk, ee: epoch_fn(qq, sp, JK, kk, ee, cfg.hp)
+            run = lambda qq, kk, ee: epoch_fn(qq, sp, JK, kk, ee, hp)
             nb_bias_steps = 0
+            nb_gather_steps = 0 if mf_only else -(-sp.nnz // cfg.batch)
     compile_secs = reg.span_durations("train.compile")[-1]
 
     history = []
@@ -234,8 +252,9 @@ def fit(train_coo, test_coo, shape, cfg: FitConfig,
             jax.block_until_ready(jax.tree.leaves(state)[0])
         t_train += reg.span_durations("train.epoch")[-1]
         reg.counter_add("train.epochs")
-        # steps whose neighbour b̂ took the vectorised lookup
+        # steps whose neighbour b̂ took the vectorised lookup, and the rest
         reg.counter_add("train.nb_bias_lookup_steps", nb_bias_steps)
+        reg.counter_add("train.nb_bias_gather_steps", nb_gather_steps)
         if cfg.eval_every and (ep + 1) % cfg.eval_every == 0:
             with reg.span("train.epoch.eval"):
                 p_eval = to_public(state)

@@ -132,3 +132,61 @@ def test_fit_counts_lookup_steps(tiny_dataset, monkeypatch):
         assert "train.nb_bias_lookup_steps" in reg.counters
         assert reg.counter("train.nb_bias_lookup_steps") == want
         assert stats["nb_lo"] > 0
+
+
+def test_gather_steps_count_every_step_past_the_crossover(tiny_sparse):
+    """`sgd.nb_bias_gather_steps` counts each CULSH step whose width puts
+    its lookup past the crossover (and every step of a CPU program), and
+    none below it: with the lookup steps, every step of the epoch."""
+    sp = tiny_sparse
+    sched = conflict_free_schedule(np.asarray(sp.rows), np.asarray(sp.cols),
+                                   batch=128, M=sp.M, N=sp.N, seed=0)
+    steps = [s.shape[0] for s in sched.tier_starts]
+    lo = sched.lo_starts.shape[0]
+    total = sum(steps) + lo
+    kw = dict(mf_only=False, platform="tpu")
+    gather = lambda N, **k: sgd.nb_bias_gather_steps(sched, N, 4, **kw | k)
+    lookup = lambda N: sgd.nb_bias_lookup_steps(sched, N, 4, **kw)
+    N = ops.ONEHOT_ITEMS_PER_LOOKUP * 4 * sched.widths[0] // (
+        4 * sched.widths[0] + ops.ONEHOT_TABLE_LOOKUPS)
+    assert gather(sp.N) == 0
+    assert gather(N) == sum(steps[1:])
+    assert gather(N + 1) == total
+    for n in (sp.N, N, N + 1, 624961):
+        assert gather(n) + lookup(n) == total
+    assert gather(sp.N, platform="cpu") == total
+    assert gather(N + 1, mf_only=True) == 0
+    # the published catalogs: ml10m's 10,677 items look b̂ up at every
+    # width, the KDD-11 catalog's 624,961 gather at every width
+    for w in (512, 256, 128, 64):
+        assert ops.nb_bias_vectorised(10677, 32 * w)
+        assert not ops.nb_bias_vectorised(624961, 32 * w)
+
+
+def test_fit_counts_gather_steps_and_times_the_neighbour_search(
+        tiny_dataset, monkeypatch):
+    """`fit` adds its gather steps to ``train.nb_bias_gather_steps`` once
+    per epoch — all of them on a CPU, none where a TPU program looks
+    every b̂ up — and times simLSH's encoding and top-K in spans inside
+    ``train.neighbours``."""
+    from repro import obs
+    from repro.data.sparse import train_test_split
+    from repro.train.trainer import FitConfig, fit
+    spec, rows, cols, vals, _ = tiny_dataset
+    tr, te = train_test_split(np.random.default_rng(0), rows, cols, vals)
+    cfg = FitConfig(F=8, K=4, epochs=2, cf_batch=128, batch=128,
+                    eval_every=0)
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        reg = obs.Registry(enabled=True)
+        res = fit(tr, te, (spec.M, spec.N), cfg, registry=reg)
+        stats = res.schedule_stats
+        every = 2 * (stats["nb_cf"] + stats["nb_lo"])
+        assert reg.counter("train.nb_bias_gather_steps") == (
+            every if backend == "cpu" else 0)
+        assert (reg.counter("train.nb_bias_gather_steps")
+                + reg.counter("train.nb_bias_lookup_steps")) == every
+    spans = {n: (t0, t0 + d) for n, t0, d, _, _ in reg.spans}
+    outer = spans["train.neighbours"]
+    for part in ("train.neighbours.encode", "train.neighbours.topk"):
+        assert outer[0] <= spans[part][0] <= spans[part][1] <= outer[1]
